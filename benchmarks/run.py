@@ -14,6 +14,9 @@ SUITES = ["kernels", "index_sizes", "build", "query_paths", "refresh", "recall"]
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     wanted = sys.argv[1:] or SUITES
     print("name,us_per_call,derived")
     failures = 0

@@ -75,7 +75,7 @@ def train_kmeans(
     inertia = float("inf")
     for _ in range(iters):
         cen_j, counts, inertia_j = _lloyd_step(pts_j, jnp.asarray(centroids), k)
-        centroids = np.asarray(cen_j)
+        centroids = np.array(cen_j)  # a copy: the repair below writes to it
         counts = np.asarray(counts)
         inertia = float(inertia_j)
         if repair_empty and (counts == 0).any():

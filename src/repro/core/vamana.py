@@ -44,6 +44,11 @@ class VamanaParams:
         return {"R": str(self.R), "L": str(self.L), "alpha": str(self.alpha), "metric": self.metric}
 
 
+# queries per traversal call of the search methods (each batch also costs
+# one gather_rerank call on the PQ paths)
+QUERY_BATCH = 64
+
+
 # ---------------------------------------------------------------------------
 # jit'd primitives.  All take padded fixed shapes; `n_valid` bounds real ids.
 # ---------------------------------------------------------------------------
@@ -410,7 +415,7 @@ class VamanaGraph:
         queries: np.ndarray,
         k: int,
         L: Optional[int] = None,
-        batch: int = 64,
+        batch: int = QUERY_BATCH,
     ) -> Tuple[np.ndarray, np.ndarray]:
         """Full-precision beam search.  Returns (dists (Q,k), ids (Q,k));
         tombstoned nodes traversed but filtered (paper §7.3)."""
@@ -452,7 +457,7 @@ class VamanaGraph:
         k: int,
         L: Optional[int] = None,
         rerank: bool = True,
-        batch: int = 64,
+        batch: int = QUERY_BATCH,
     ) -> Tuple[np.ndarray, np.ndarray]:
         """Stage-A probe: PQ-approximate traversal + full-precision rerank of
         the candidate pool (paper §6)."""
@@ -531,7 +536,7 @@ class VamanaGraph:
         unique_masks: np.ndarray,
         mask_idx: Optional[np.ndarray] = None,
         L: Optional[int] = None,
-        batch: int = 64,
+        batch: int = QUERY_BATCH,
         use_pq: bool = False,
     ) -> Tuple[np.ndarray, np.ndarray]:
         """Predicate-aware beam search (the filtered-DiskANN traversal).

@@ -12,6 +12,13 @@ Four kernel families, each with a pure-jnp oracle in
 
 On CPU the kernels run under ``interpret=True`` for validation; production
 CPU paths dispatch to the oracles (see ops.py backend rules).
+
+Every f32 contraction in the kernels (and in the oracles) runs at
+``Precision.HIGHEST``: on TPU the default f32 matmul is one bf16 pass, and
+the expanded distance form |q|^2 - 2 q.x + |x|^2 turns its rounding into a
+percent-level distance error (2.4e-2 relative on a TPU v5e at D=768).  The
+one-hot contractions (ADC lookups, the pooled score gather) need it too:
+at one bf16 pass they round the LUT entries and scores they select.
 """
 
 from repro.kernels.ops import (  # noqa: F401

@@ -14,7 +14,8 @@ leave MXU headroom for a wider N tile.  This module picks tiles per
 - :func:`get_tiles` is the hot-path lookup ops.py calls when a wrapper is
   invoked with ``tile_q=None``: row counts bucket to the next power of two
   and D to the next multiple of 128 so one sweep generalizes; a cache miss
-  returns :data:`DEFAULT_TILES`.
+  returns :data:`DEFAULT_TILES`.  So does a fixture swept on another
+  platform (``meta.backend``): CPU timings say nothing about TPU tiles.
 
 Never-regress guarantee: the candidate list always contains
 :data:`DEFAULT_TILES`, and a challenger must beat the default by more than
@@ -36,6 +37,8 @@ import json
 import time
 from pathlib import Path
 from typing import Dict, Optional, Tuple
+
+import jax
 
 DEFAULT_TILES: Tuple[int, int] = (8, 128)
 
@@ -81,6 +84,8 @@ def _load_cache(path_str: str) -> Dict[str, Tuple[int, int]]:
         raw = json.loads(path.read_text())
     except (OSError, json.JSONDecodeError):  # unreadable fixture → defaults
         return {}
+    if raw.get("meta", {}).get("backend") != jax.devices()[0].platform:
+        return {}  # swept on another platform: its timings do not transfer
     tiles = raw.get("tiles", {})
     out: Dict[str, Tuple[int, int]] = {}
     for key, val in tiles.items():
@@ -97,8 +102,9 @@ def get_tiles(
     n_rows: int, d: int, flavor: str, cache_path: Optional[Path] = None
 ) -> Tuple[int, int]:
     """Tile choice for a kernel dispatch: measured winner when the sweep has
-    seen this ``(rows, D, flavor)`` bucket, :data:`DEFAULT_TILES` otherwise
-    (cache miss, missing fixture, unknown flavor — never an error)."""
+    seen this ``(rows, D, flavor)`` bucket on the running platform,
+    :data:`DEFAULT_TILES` otherwise (cache miss, missing fixture, fixture
+    swept on another platform, unknown flavor — never an error)."""
     cache = _load_cache(str(cache_path or _CACHE_PATH))
     return cache.get(cache_key(n_rows, d, flavor), DEFAULT_TILES)
 
@@ -220,8 +226,6 @@ def sweep(
 ) -> Dict[str, Tuple[int, int]]:
     """Run the full sweep and write the JSON fixture.  Keys collapse by
     bucket, so overlapping (rows, dims) points just overwrite each other."""
-    import jax
-
     tiles: Dict[str, Tuple[int, int]] = {}
     for flavor in flavors:
         for n_rows in row_counts:

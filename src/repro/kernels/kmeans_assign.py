@@ -11,6 +11,10 @@ and keeps a **running (min, argmin)** in the output refs — the canonical
 Pallas cross-step reduction idiom.  Centroid tiles therefore never need to
 fit all of K in VMEM at once.
 
+The outputs are lane-dense ``(1, N)`` rows written in ``(1, TILE_N)``
+blocks: a 1-D ``(N,)`` output is laid out by XLA in 1024-element tiles and
+by Mosaic in 256-element ones, and the TPU compiler refuses the mismatch.
+
 VMEM per step (TILE_N=256, TILE_K=128, D≤1024 f32): x 1 MB, c 0.5 MB.
 """
 
@@ -30,12 +34,13 @@ def _kmeans_assign_kernel(x_ref, c_ref, dist_ref, idx_ref, *, tile_k: int):
     cross = jax.lax.dot_general(
         x, c, dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )  # (TILE_N, TILE_K)
     x2 = jnp.sum(x * x, axis=-1, keepdims=True)
     c2 = jnp.sum(c * c, axis=-1)[None, :]
     d = x2 - 2.0 * cross + c2  # (TILE_N, TILE_K)
-    local_min = jnp.min(d, axis=1)  # (TILE_N,)
-    local_arg = jnp.argmin(d, axis=1).astype(jnp.int32) + k_step * tile_k
+    local_min = jnp.min(d, axis=1)[None, :]  # (1, TILE_N)
+    local_arg = jnp.argmin(d, axis=1).astype(jnp.int32)[None, :] + k_step * tile_k
 
     @pl.when(k_step == 0)
     def _init():
@@ -57,7 +62,7 @@ def kmeans_assign_pallas(
     *,
     tile_n: int = 256,
     tile_k: int = 128,
-    interpret: bool = True,
+    interpret: bool = False,
 ):
     """Returns (assignments (N,) int32, sq_distances (N,) f32).
 
@@ -76,13 +81,13 @@ def kmeans_assign_pallas(
             pl.BlockSpec((tile_k, d), lambda i, j: (j, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((tile_n,), lambda i, j: (i,)),
-            pl.BlockSpec((tile_n,), lambda i, j: (i,)),
+            pl.BlockSpec((1, tile_n), lambda i, j: (0, i)),
+            pl.BlockSpec((1, tile_n), lambda i, j: (0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((n,), jnp.float32),
-            jax.ShapeDtypeStruct((n,), jnp.int32),
+            jax.ShapeDtypeStruct((1, n), jnp.float32),
+            jax.ShapeDtypeStruct((1, n), jnp.int32),
         ],
         interpret=interpret,
     )(points.astype(jnp.float32), centroids.astype(jnp.float32))
-    return idx, dist
+    return idx[0], dist[0]

@@ -162,6 +162,9 @@ def _masked_exact_kernel(q_ref, x_ref, m_ref, od_ref, oi_ref, *, metric, k, tile
     cross = jax.lax.dot_general(
         q, x, dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
+        # f32 at full precision (Mosaic's default is one bf16 pass, a
+        # percent-level distance error); bf16 products are exact anyway
+        precision=jax.lax.Precision.HIGHEST if q.dtype == jnp.float32 else None,
     )  # (TILE_Q, TILE_N); bf16 inputs run the MXU at bf16 rate, f32 accum
     if metric == "l2":
         # norms upcast first: only the VALUES are reduced precision
@@ -232,6 +235,7 @@ def _masked_pq_kernel(lut_ref, codes_ref, m_ref, od_ref, oi_ref, *, K, k, tile_n
         onehot.reshape(tn, m_sub * K),
         dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )  # (TILE_Q, TILE_N)
     d = jnp.where(m_mask > 0.5, d, MASKED)
     _merge_tile(d, j, tile_n, od_ref, oi_ref, k)
@@ -258,7 +262,7 @@ def masked_exact_topk_pallas(
     metric: str = "l2",
     tile_q: int = 8,
     tile_n: int = 128,
-    interpret: bool = True,
+    interpret: bool = False,
     scales: jnp.ndarray | None = None,
 ):
     """Masked exact top-k.  queries (Q, D), points (N, D), mask (1, N) f32
@@ -327,7 +331,7 @@ def masked_exact_topk_multi_pallas(
     metric: str = "l2",
     tile_q: int = 8,
     tile_n: int = 128,
-    interpret: bool = True,
+    interpret: bool = False,
     scales: jnp.ndarray | None = None,
 ):
     """Per-query-mask exact top-k.  queries (Q, D), points (N, D),
@@ -392,7 +396,7 @@ def masked_pq_topk_pallas(
     *,
     tile_q: int = 8,
     tile_n: int = 128,
-    interpret: bool = True,
+    interpret: bool = False,
 ):
     """Masked PQ-ADC top-k.  luts (Q, m, K) f32, codes (N, m) int32, mask
     (1, N) f32.  Same alignment/sentinel contract as
@@ -441,6 +445,7 @@ def _unified_kernel(
     cross = jax.lax.dot_general(
         q, x, dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )
     if metric == "l2":
         q2 = jnp.sum(q * q, axis=-1, keepdims=True)
@@ -464,6 +469,7 @@ def _unified_kernel(
             lut[:, c, :], onehot_c,
             dimension_numbers=(((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST,
         )  # (TILE_Q, TILE_N)
         score_ref[...] += jnp.where(is_adc, part, 0.0)
     d = jnp.where(s > 0.5, score_ref[...], MASKED)
@@ -517,7 +523,7 @@ def unified_masked_topk_pallas(
     metric: str = "l2",
     tile_q: int = 8,
     tile_n: int = 128,
-    interpret: bool = True,
+    interpret: bool = False,
 ):
     """Single-dispatch mixed-flavor masked top-k.  queries (Q, D) f32,
     points (N, D) f32, luts (Q, m, K) f32, codes (N, m) int32, selector
@@ -576,7 +582,7 @@ def masked_pq_topk_multi_pallas(
     *,
     tile_q: int = 8,
     tile_n: int = 128,
-    interpret: bool = True,
+    interpret: bool = False,
 ):
     """Per-query-mask PQ-ADC top-k.  luts (Q, m, K) f32, codes (N, m) int32,
     masks (Q, N) f32.  Same alignment/sentinel contract as

@@ -50,6 +50,7 @@ Masked-op contract (``masked_exact_topk`` / ``masked_pq_topk`` and their
 
 from __future__ import annotations
 
+import functools
 from typing import Optional, Tuple
 
 import jax
@@ -71,11 +72,12 @@ from repro.kernels.rerank import gather_rerank_pallas, rerank_distances_pallas
 _BIG = jnp.float32(3.4e38)  # ~f32 max; safe "never wins" sentinel
 
 
+@functools.cache
 def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:  # pragma: no cover
-        return False
+    """Whether the default device is a TPU, decided once per process.  A
+    failure to enumerate devices propagates: a broken accelerator must not
+    silently turn every op into the CPU oracle or interpret mode."""
+    return jax.devices()[0].platform == "tpu"
 
 
 def _resolve(backend: str) -> str:

@@ -48,6 +48,7 @@ def _pq_scan_kernel(lut_ref, codes_ref, out_ref, *, K: int):
         onehot_flat,
         dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )
 
 
@@ -58,7 +59,7 @@ def pq_scan_pallas(
     *,
     tile_q: int = 8,
     tile_n: int = 128,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jnp.ndarray:
     """ADC scores via the one-hot-matmul kernel.
 

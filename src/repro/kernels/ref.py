@@ -19,17 +19,25 @@ def l2_distances(queries: jnp.ndarray, points: jnp.ndarray) -> jnp.ndarray:
 
     queries: (Q, D) f32;  points: (N, D) f32  ->  (Q, N) f32.
     Uses the expanded form |q|^2 - 2 q.x + |x|^2 (same math as the kernel so
-    numerical behaviour matches to float tolerance).
+    numerical behaviour matches to float tolerance).  The cross term runs at
+    ``HIGHEST`` precision: TPU's default f32 matmul is a single bf16 pass,
+    whose error survives the cancellation in the expanded form as a
+    percent-level distance error.
     """
     q2 = jnp.sum(queries * queries, axis=-1, keepdims=True)  # (Q, 1)
     x2 = jnp.sum(points * points, axis=-1)[None, :]  # (1, N)
-    cross = queries @ points.T  # (Q, N)
+    cross = _matmul_t(queries, points)  # (Q, N)
     return q2 - 2.0 * cross + x2
 
 
 def ip_distances(queries: jnp.ndarray, points: jnp.ndarray) -> jnp.ndarray:
     """Negative inner product ("distance": smaller is closer)."""
-    return -(queries @ points.T)
+    return -_matmul_t(queries, points)
+
+
+def _matmul_t(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
+    """``a @ b.T`` at full f32 precision on every backend."""
+    return jnp.matmul(a, b.T, precision=jax.lax.Precision.HIGHEST)
 
 
 def pq_adc_scores(luts: jnp.ndarray, codes: jnp.ndarray) -> jnp.ndarray:
@@ -62,7 +70,9 @@ def build_pq_luts(
         diff = q_sub[:, :, None, :] - codebook[None, :, :, :]  # (Q, m, K, dsub)
         return jnp.sum(diff * diff, axis=-1)
     if metric == "ip":
-        return -jnp.einsum("qmd,mkd->qmk", q_sub, codebook)
+        return -jnp.einsum(
+            "qmd,mkd->qmk", q_sub, codebook, precision=jax.lax.Precision.HIGHEST
+        )
     raise ValueError(f"unknown metric {metric}")
 
 
@@ -183,7 +193,7 @@ def gather_rerank(
     safe = jnp.clip(pids, 0, x.shape[0] - 1)
     vecs = x[safe]  # (Q, P, D)
     if metric == "ip":
-        d = -jnp.einsum("qpd,qd->qp", vecs, q)
+        d = -jnp.einsum("qpd,qd->qp", vecs, q, precision=jax.lax.Precision.HIGHEST)
     else:
         diff = vecs - q[:, None, :]
         d = jnp.sum(diff * diff, axis=-1)

@@ -59,6 +59,7 @@ def _rerank_kernel(q_ref, x_ref, out_ref, *, metric: str):
     cross = jax.lax.dot_general(
         q, x, dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )  # (TILE_Q, TILE_N)
     if metric == "l2":
         q2 = jnp.sum(q * q, axis=-1, keepdims=True)  # (TILE_Q, 1)
@@ -78,7 +79,7 @@ def rerank_distances_pallas(
     metric: str = "l2",
     tile_q: int = 128,
     tile_n: int = 128,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jnp.ndarray:
     """Exact distance matrix (Q, N).  Q, N, D must be tile-aligned
     (the ops.py wrapper pads)."""
@@ -117,6 +118,7 @@ def _gather_rerank_kernel(
     cross = jax.lax.dot_general(
         q, x, dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )  # (TILE_Q, TILE_N)
     if metric == "l2":
         q2 = jnp.sum(q * q, axis=-1, keepdims=True)
@@ -133,6 +135,7 @@ def _gather_rerank_kernel(
     contrib = jax.lax.dot_general(
         onehot, d, dimension_numbers=(((2,), (1,)), ((0,), (0,))),
         preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )
     acc_ref[...] += contrib
 
@@ -156,7 +159,7 @@ def gather_rerank_pallas(
     metric: str = "l2",
     tile_q: int = 8,
     tile_n: int = 128,
-    interpret: bool = True,
+    interpret: bool = False,
 ):
     """Pooled gather-rerank.  queries (Q, D) f32, points (N, D) f32,
     pool_ids (Q, P) int32 (slots < 0 are sentinels and stay (MASKED, -1);

@@ -26,7 +26,7 @@ import numpy as np
 from repro.core.blobs import ShardLocationMap, decode_shard_blob, encode_shard_blob
 from repro.runtime import planner
 from repro.runtime.predicates import row_group_mask
-from repro.core.vamana import VamanaGraph, VamanaParams, build_vamana
+from repro.core.vamana import QUERY_BATCH, VamanaGraph, VamanaParams, build_vamana
 from repro.core.pq import PQCodebook, encode as pq_encode
 from repro.iceberg.puffin import _decompress  # codec shared with Puffin blobs
 from repro.kernels import device_cache, ops
@@ -149,9 +149,10 @@ class Executor:
         # attempt runs on its own scheduler thread), so concurrent probes
         # on one executor cannot misattribute each other's dispatches.
         self.masked_kernel_dispatches = 0
-        # gather-rerank kernel calls (ADC-pool reranks + quantized-scan
-        # guards).  Deliberately a SEPARATE counter: rerank stages have
-        # never counted toward masked_kernel_dispatches, and the dispatch-
+        # gather-rerank kernel calls (ADC-pool reranks, quantized-scan
+        # guards, PQ-traversal pool reranks).  Deliberately a SEPARATE
+        # counter: rerank stages have never counted toward
+        # masked_kernel_dispatches, and the dispatch-
         # count invariants the fragment tests assert must keep meaning
         # "masked scan dispatches".
         self.rerank_kernel_dispatches = 0
@@ -288,11 +289,16 @@ class Executor:
             self.masked_kernel_dispatches += 1
         self._dispatch_tls.count = getattr(self._dispatch_tls, "count", 0) + 1
 
-    def _count_rerank(self) -> None:
-        """Record one gather-rerank kernel call (see the counter's note in
+    def _count_rerank(self, calls: int = 1) -> None:
+        """Record gather-rerank kernel calls (see the counter's note in
         __init__ — separate from masked-scan dispatch accounting)."""
         with self._lock:
-            self.rerank_kernel_dispatches += 1
+            self.rerank_kernel_dispatches += calls
+
+    def _count_graph_reranks(self, queries: np.ndarray) -> None:
+        """A PQ traversal (``search_pq`` / ``search_masked(use_pq=True)``)
+        reranks its pool with one ``gather_rerank`` call per query batch."""
+        self._count_rerank(-(-len(queries) // QUERY_BATCH))
 
     def _task_dispatches(self) -> int:
         return getattr(self._dispatch_tls, "count", 0)
@@ -724,13 +730,16 @@ class Executor:
         does)."""
         w = max(1, min(int(width), graph.num_live))
         L = max(task.L, min(int(k), graph.num_live))
+        use_pq = task.use_pq and graph.pq is not None
+        if use_pq:
+            self._count_graph_reranks(queries)
         return graph.search_masked(
             queries,
             w,
             np.stack(unique_masks),
             row_index,
             L=L,
-            use_pq=task.use_pq and graph.pq is not None,
+            use_pq=use_pq,
         )
 
     def _masked_beam(
@@ -875,6 +884,7 @@ class Executor:
         k_eff = min(width or task.k * task.oversample, graph.num_live)
         L = max(task.L, k_eff)
         if task.use_pq and graph.pq is not None:
+            self._count_graph_reranks(q)
             return graph.search_pq(q, k_eff, L=L)
         return graph.search(q, k_eff, L=L)
 

@@ -22,7 +22,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, Sharding
 
 from repro.core.vamana import _beam_search
 
@@ -55,8 +55,14 @@ class DeviceAnnIndex:
         )
 
     @staticmethod
-    def from_graphs(graphs, payloads=None, dtype=jnp.float32) -> "DeviceAnnIndex":
-        """Pack host VamanaGraphs (equal capacity) into device arrays."""
+    def from_graphs(
+        graphs, payloads=None, dtype=jnp.float32, sharding: Optional[Sharding] = None
+    ) -> "DeviceAnnIndex":
+        """Pack host VamanaGraphs (equal capacity) into device arrays.
+
+        With ``sharding`` (e.g. ``NamedSharding(mesh, P("data"))``) every
+        array goes from the host straight onto its shard's devices, so a
+        sharded index never lands whole on the default device."""
         cap = max(g.vectors.shape[0] for g in graphs)
         R = max(g.adjacency.shape[1] for g in graphs)
         D = graphs[0].dim
@@ -77,11 +83,11 @@ class DeviceAnnIndex:
             if payloads is not None:
                 pl[i, : len(payloads[i])] = payloads[i]
         return DeviceAnnIndex(
-            vectors=jnp.asarray(vecs, dtype),
-            adjacency=jnp.asarray(adj),
-            medoids=jnp.asarray(meds),
-            counts=jnp.asarray(counts),
-            payload=jnp.asarray(pl) if pl is not None else None,
+            vectors=jax.device_put(vecs.astype(dtype), sharding),
+            adjacency=jax.device_put(adj, sharding),
+            medoids=jax.device_put(meds, sharding),
+            counts=jax.device_put(counts, sharding),
+            payload=jax.device_put(pl, sharding) if pl is not None else None,
         )
 
     @staticmethod
@@ -150,8 +156,6 @@ def make_probe_fn(
         negg, gi = jax.lax.top_k(-all_d, k)
         return -negg, jnp.take_along_axis(all_p, gi, axis=1)
 
-    from jax.experimental.shard_map import shard_map
-
     pspec_sharded = P(shard_axes if len(shard_axes) > 1 else shard_axes[0])
     pspec_none = P()
     in_specs = (
@@ -166,12 +170,12 @@ def make_probe_fn(
 
     def probe(index: DeviceAnnIndex, queries: jnp.ndarray):
         payload = index.payload if index.payload is not None else index.adjacency[:, :, 0]
-        return shard_map(
+        return jax.shard_map(
             local_probe,
             mesh=mesh,
             in_specs=in_specs,
             out_specs=out_specs,
-            check_rep=False,
+            check_vma=False,
         )(index.vectors, index.adjacency, index.medoids, index.counts, payload, queries)
 
     return probe

@@ -798,14 +798,17 @@ def test_autotune_defaults_on_cache_miss(tmp_path):
 def test_autotune_reads_fixture_and_rejects_unknown_tiles(tmp_path):
     import json
 
+    import jax
+
     from repro.kernels import autotune
 
     path = tmp_path / "cache.json"
     path.write_text(json.dumps({
+        "meta": {"backend": jax.devices()[0].platform},
         "tiles": {
             autotune.cache_key(4096, 128, "exact"): [16, 256],
             autotune.cache_key(4096, 128, "pq"): [13, 77],  # never swept
-        }
+        },
     }))
     autotune.clear_cache()
     assert autotune.get_tiles(4096, 128, "exact", cache_path=path) == (16, 256)
@@ -813,6 +816,25 @@ def test_autotune_reads_fixture_and_rejects_unknown_tiles(tmp_path):
     assert autotune.get_tiles(3000, 128, "exact", cache_path=path) == (16, 256)
     # invalid tiles are discarded -> defaults
     assert autotune.get_tiles(4096, 128, "pq", cache_path=path) == autotune.DEFAULT_TILES
+    autotune.clear_cache()
+
+
+@pytest.mark.parametrize("meta", [{"backend": "other"}, {}, None])
+def test_autotune_ignores_fixture_from_another_platform(tmp_path, meta):
+    """Tiles timed on one platform never steer another: a fixture whose
+    ``meta.backend`` is not the running platform (or is not recorded) gives
+    the defaults, as the committed CPU-swept fixture does on a TPU."""
+    import json
+
+    from repro.kernels import autotune
+
+    fixture = {"tiles": {autotune.cache_key(4096, 128, "exact"): [16, 256]}}
+    if meta is not None:
+        fixture["meta"] = meta
+    path = tmp_path / "cache.json"
+    path.write_text(json.dumps(fixture))
+    autotune.clear_cache()
+    assert autotune.get_tiles(4096, 128, "exact", cache_path=path) == autotune.DEFAULT_TILES
     autotune.clear_cache()
 
 

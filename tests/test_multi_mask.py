@@ -165,6 +165,26 @@ def test_hetero_pq_batch_is_one_dispatch_per_shard(pq_plane_cluster):
         assert _locs_d(a) == _locs_d(b)
 
 
+def test_pq_traversal_reranks_are_counted(pq_plane_cluster):
+    """An unfiltered PQ probe reranks each traversal's candidate pool with
+    one gather_rerank call per shard fragment (8 queries: one query batch),
+    counted with the executors' other rerank kernel calls; a full-precision
+    traversal reranks nothing."""
+    c, t, X, price, rep = pq_plane_cluster
+    Q = _queries(X, 8, seed=7)
+
+    def reranks():
+        return sum(ex.rerank_kernel_dispatches for ex in c.executors)
+
+    before = reranks()
+    br = c.coordinator.probe_batch("emb", Q, 10, strategy="diskann", use_pq=True)
+    assert br.probe_fragments >= 1
+    assert reranks() - before == br.probe_fragments
+    before = reranks()
+    c.coordinator.probe_batch("emb", Q, 10, strategy="diskann", use_pq=False)
+    assert reranks() == before
+
+
 def test_mixed_kernel_and_postfilter_batch_matches_sequential(plane_cluster):
     """A batch mixing unfiltered, mask-planned, and postfilter-planned
     queries: kernel rows ride the plane, the beam group loop survives only

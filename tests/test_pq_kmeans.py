@@ -36,6 +36,15 @@ def test_kmeans_no_empty_clusters(rng):
     assert (counts > 0).all()
 
 
+def test_kmeans_repairs_empty_clusters_of_duplicate_points():
+    """Two distinct points repeated, k=4: seeding duplicates centroids, two
+    clusters go empty each step, and the repair re-seeds them in place."""
+    X = np.repeat(np.array([[0.0, 0.0], [1.0, 1.0]], np.float32), 50, axis=0)
+    cents, inertia = train_kmeans(X, 4, iters=3, seed=0)
+    assert cents.shape == (4, 2) and np.isfinite(cents).all()
+    assert inertia == 0.0
+
+
 def test_pq_roundtrip_shapes(rng):
     X = rng.normal(size=(2000, 64)).astype(np.float32)
     pq = train_pq(X, m=8, nbits=6, iters=5)

@@ -58,6 +58,36 @@ def test_abstract_index_lowering(device_index):
     assert compiled is not None
 
 
+def test_from_graphs_places_shards_on_their_sharding(device_index):
+    """With a sharding, every index array lands straight on the mesh's
+    devices (not whole on the default one), and the probe answers the same."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    X, idx = device_index
+    mesh = make_debug_mesh(1, 1)
+    rng = np.random.default_rng(0)
+    half = len(X) // 2
+    graphs = [
+        build_vamana(part, VamanaParams(R=12, L=24), passes=1, batch=128)
+        for part in (X[:half], X[half:])
+    ]
+    payloads = [np.arange(half), np.arange(half, len(X))]
+    sharded = DeviceAnnIndex.from_graphs(
+        graphs, payloads=payloads, sharding=NamedSharding(mesh, P("data"))
+    )
+    for leaf, spec in zip(
+        jax.tree.leaves(sharded), jax.tree.leaves(sharded.shardings(mesh))
+    ):
+        assert leaf.sharding == spec
+    probe = jax.jit(make_probe_fn(mesh, k=10, L=24))
+    Q = X[rng.choice(len(X), 8)]
+    with mesh:
+        d_a, i_a = probe(idx, jnp.asarray(Q))
+        d_b, i_b = probe(sharded, jnp.asarray(Q))
+    np.testing.assert_array_equal(np.asarray(i_a), np.asarray(i_b))
+    np.testing.assert_allclose(np.asarray(d_a), np.asarray(d_b))
+
+
 def test_knn_lm_decode_runs_and_mixes():
     cfg = dataclasses.replace(reduced(get_config("qwen2.5-3b")), num_layers=2)
     model = build_model(cfg)

@@ -1,0 +1,204 @@
+"""Compile the main-path programs for a described TPU v5e, no chip needed.
+
+The TPU compiler runs here against a ``v5e:2x2`` topology description at the
+paper's width (D=768), a 65,536-row shard, a 64-query batch and k=100.  It
+refuses what interpret mode accepts: unaligned or mislaid blocks, too much
+VMEM, an unpartitionable sharded program.  Nothing runs, so these tests say
+nothing about results or speed.
+
+The topology is described only inside the module fixture: loading the TPU
+library while a module is imported would give xdist workers different tests.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+from repro.core.vamana import _beam_search, _masked_beam_search, _robust_prune
+from repro.kernels.kmeans_assign import kmeans_assign_pallas
+from repro.kernels.masked_topk import (
+    masked_exact_topk_multi_pallas,
+    masked_exact_topk_pallas,
+    masked_pq_topk_pallas,
+    unified_masked_topk_pallas,
+)
+from repro.kernels.pq_scan import pq_scan_pallas
+from repro.kernels.rerank import gather_rerank_pallas, rerank_distances_pallas
+from repro.serving.device_index import DeviceAnnIndex, make_probe_fn
+
+D, N, Q, K = 768, 65536, 64, 100
+R, L = 64, 100
+MAX_ITERS = int(1.3 * L) + 8
+PQ_M, PQ_K = 48, 256
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    # entries compiled for a described chip cannot be read back without one
+    was_enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        desc = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means "cannot describe"
+        jax.config.update("jax_enable_compilation_cache", was_enabled)
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was_enabled)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compiled_kernel(compiled) -> bool:
+    return "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("tiles", [(8, 128), (16, 256), (32, 128)])
+@pytest.mark.parametrize("dtype", ["f32", "bf16", "int8"])
+def test_masked_exact_topk_compiles(one_chip, dtype, tiles):
+    dt = {"f32": jnp.float32, "bf16": jnp.bfloat16, "int8": jnp.int8}[dtype]
+    args = [
+        _sds((Q, D), dt, one_chip),
+        _sds((N, D), dt, one_chip),
+        _sds((1, N), jnp.float32, one_chip),
+    ]
+    kw = dict(k=K, tile_q=tiles[0], tile_n=tiles[1], interpret=False)
+    if dtype == "int8":
+        kw["scales"] = _sds((1, 2), jnp.float32, one_chip)
+    assert _compiled_kernel(masked_exact_topk_pallas.lower(*args, **kw).compile())
+
+
+def test_masked_exact_topk_multi_compiles(one_chip):
+    compiled = masked_exact_topk_multi_pallas.lower(
+        _sds((Q, D), jnp.float32, one_chip),
+        _sds((N, D), jnp.float32, one_chip),
+        _sds((Q, N), jnp.float32, one_chip),
+        k=K, interpret=False,
+    ).compile()
+    assert _compiled_kernel(compiled)
+
+
+@pytest.mark.parametrize("m", [8, PQ_M])
+def test_masked_pq_topk_compiles(one_chip, m):
+    compiled = masked_pq_topk_pallas.lower(
+        _sds((Q, m, PQ_K), jnp.float32, one_chip),
+        _sds((N, m), jnp.int32, one_chip),
+        _sds((1, N), jnp.float32, one_chip),
+        k=K, interpret=False,
+    ).compile()
+    assert _compiled_kernel(compiled)
+
+
+def test_unified_masked_topk_compiles(one_chip):
+    compiled = unified_masked_topk_pallas.lower(
+        _sds((Q, D), jnp.float32, one_chip),
+        _sds((N, D), jnp.float32, one_chip),
+        _sds((Q, PQ_M, PQ_K), jnp.float32, one_chip),
+        _sds((N, PQ_M), jnp.int32, one_chip),
+        _sds((Q, N), jnp.float32, one_chip),
+        k=K, interpret=False,
+    ).compile()
+    assert _compiled_kernel(compiled)
+
+
+@pytest.mark.parametrize("pool", [256, 512])
+def test_gather_rerank_compiles(one_chip, pool):
+    compiled = gather_rerank_pallas.lower(
+        _sds((Q, D), jnp.float32, one_chip),
+        _sds((N, D), jnp.float32, one_chip),
+        _sds((Q, pool), jnp.int32, one_chip),
+        k=K, interpret=False,
+    ).compile()
+    assert _compiled_kernel(compiled)
+
+
+def test_rerank_distances_and_pq_scan_compile(one_chip):
+    dists = rerank_distances_pallas.lower(
+        _sds((128, D), jnp.float32, one_chip),  # ops.exact_distances pads Q to 128
+        _sds((N, D), jnp.float32, one_chip),
+        interpret=False,
+    ).compile()
+    adc = pq_scan_pallas.lower(
+        _sds((Q, PQ_M, PQ_K), jnp.float32, one_chip),
+        _sds((N, PQ_M), jnp.int32, one_chip),
+        interpret=False,
+    ).compile()
+    assert _compiled_kernel(dists) and _compiled_kernel(adc)
+
+
+def test_kmeans_assign_compiles(one_chip):
+    compiled = kmeans_assign_pallas.lower(
+        _sds((N, D), jnp.float32, one_chip),
+        _sds((128, D), jnp.float32, one_chip),
+        tile_n=256, tile_k=128, interpret=False,
+    ).compile()
+    assert _compiled_kernel(compiled)
+
+
+@pytest.mark.parametrize("use_pq", [False, True])
+def test_beam_search_compiles(one_chip, use_pq):
+    points = (N, PQ_M) if use_pq else (N, D)
+    queries = (Q, PQ_M, PQ_K) if use_pq else (Q, D)
+    compiled = _beam_search.lower(
+        _sds(points, jnp.int32 if use_pq else jnp.float32, one_chip),
+        _sds((N, R), jnp.int32, one_chip),
+        _sds((), jnp.int32, one_chip),
+        _sds((), jnp.int32, one_chip),
+        _sds(queries, jnp.float32, one_chip),
+        L, MAX_ITERS, "l2", use_pq,
+    ).compile()
+    assert compiled.memory_analysis() is not None
+
+
+def test_masked_beam_search_compiles(one_chip):
+    compiled = _masked_beam_search.lower(
+        _sds((N, D), jnp.float32, one_chip),
+        _sds((N, R), jnp.int32, one_chip),
+        _sds((), jnp.int32, one_chip),
+        _sds((), jnp.int32, one_chip),
+        _sds((Q, D), jnp.float32, one_chip),
+        _sds((2, N), jnp.bool_, one_chip),
+        _sds((Q,), jnp.int32, one_chip),
+        L, 4 * K, MAX_ITERS, "l2", False,
+    ).compile()
+    assert compiled.memory_analysis() is not None
+
+
+def test_robust_prune_compiles(one_chip):
+    batch = 128
+    compiled = _robust_prune.lower(
+        _sds((N, D), jnp.float32, one_chip),
+        _sds((batch, D), jnp.float32, one_chip),
+        _sds((batch, L + MAX_ITERS), jnp.int32, one_chip),
+        _sds((), jnp.int32, one_chip),
+        R, 1.2, "l2",
+    ).compile()
+    assert compiled.memory_analysis() is not None
+
+
+def test_probe_fn_compiles_on_four_chips(topo):
+    """The shard_map Stage-A/C probe, one 65,536-row shard per chip of a
+    2x2 host: it partitions, and the merge is an all-gather."""
+    mesh = Mesh(np.array(topo.devices[:4]).reshape(4, 1), ("data", "model"))
+    sharded = NamedSharding(mesh, P("data"))
+    abstract = DeviceAnnIndex.abstract(n_shards=4, cap=N, dim=D, R=R, dtype=jnp.float32)
+    idx = jax.tree.map(lambda s: _sds(s.shape, s.dtype, sharded), abstract)
+    queries = _sds((Q, D), jnp.float32, NamedSharding(mesh, P()))
+    compiled = jax.jit(make_probe_fn(mesh, k=K, L=L)).lower(idx, queries).compile()
+    assert "all-gather" in compiled.as_text()
+    assert compiled.memory_analysis() is not None
