@@ -77,7 +77,7 @@ from repro.runtime.planner import PlanOp, ProbePlan
 from repro.runtime.predicates import Predicate, parse_predicate, row_group_mask
 from repro.runtime.scheduler import ExecutorPool, Scheduler
 from repro.serving.cache import ShardProbeCache, query_digest
-from repro.serving.metrics import MetricsRegistry
+from repro.serving.metrics import MetricsRegistry, span, timed
 
 TOMBSTONE_REBUILD_THRESHOLD = 0.20  # paper §7.3
 
@@ -929,85 +929,86 @@ class Coordinator:
         against the CURRENT tail, since the tail may have grown or been
         compacted since the plan was captured.  Only the diskann strategy
         is plannable."""
-        queries = np.atleast_2d(np.asarray(queries, np.float32))
-        B = queries.shape[0]
-        preds = self._coerce_filters_batch(filter, B)
-        self.store.metrics.reset()
-        table = LakehouseTable(self.catalog, table_name)
-        if replay_plan is not None and strategy in ("scan", "centroid"):
-            raise ValueError(f"replay_plan is not supported for strategy={strategy!r}")
-        if strategy == "scan":
-            if preds is None or len(set(preds)) == 1:
-                report = self._probe_scan(
-                    table, queries, k, snapshot_id, pred=preds[0] if preds else None
-                )
-            else:
-                report = self._grouped_filtered(
-                    lambda q, p: self._probe_scan(table, q, k, snapshot_id, pred=p),
-                    queries,
-                    preds,
-                )
-            report.batch_size = B
-            return report
-        meta, snap, puffin_path, reader = self._resolve_index(
-            table_name, snapshot_id, as_of_ms
-        )
-        full_tail = self._resolve_tail(snap)
-        tail = full_tail if include_tail else None
-        routing = decode_routing_blob(reader.read_first(ROUTING_BLOB_TYPE))
-        shard_blobs = reader.blobs_of_type(SHARD_BLOB_TYPE)
-        strategy = self._choose_strategy(strategy, routing, shard_blobs)
-        if replay_plan is not None and strategy != "diskann":
-            raise ValueError(f"replay_plan is not supported for strategy={strategy!r}")
-        if strategy == "centroid":
-            if preds is None or len(set(preds)) == 1:
-                report = self._probe_centroid_batch(
-                    table, reader, queries, k, n_probe,
-                    pred=preds[0] if preds else None, puffin_path=puffin_path,
-                    tail=tail,
-                )
-            else:
-                # per-group batches keep per-query file ownership, so mixed
-                # filters still return exactly the sequential probes' hits
-                report = self._grouped_filtered(
-                    lambda q, p: self._probe_centroid_batch(
-                        table, reader, q, k, n_probe, pred=p,
-                        puffin_path=puffin_path, tail=tail,
-                    ),
-                    queries,
-                    preds,
-                )
-        else:
-            report = self._probe_diskann_batch(
-                table,
-                routing,
-                reader,
-                puffin_path,
-                queries,
-                k,
-                use_pq=use_pq,
-                L=L,
-                n_route=n_route,
-                preds=preds,
-                zonemap=(
-                    self._read_zonemap(reader, puffin_path)
-                    if preds and replay_plan is None
-                    else None
-                ),
-                tail=tail,
-                scan_dtype=scan_dtype,
-                oversample_override=oversample,
-                replay_plan=replay_plan,
-                cache_ctx=(
-                    (table_name, snap.snapshot_id)
-                    if self.probe_cache is not None
-                    else None
-                ),
+        with span("coordinator.probe_batch"):
+            queries = np.atleast_2d(np.asarray(queries, np.float32))
+            B = queries.shape[0]
+            preds = self._coerce_filters_batch(filter, B)
+            self.store.metrics.reset()
+            table = LakehouseTable(self.catalog, table_name)
+            if replay_plan is not None and strategy in ("scan", "centroid"):
+                raise ValueError(f"replay_plan is not supported for strategy={strategy!r}")
+            if strategy == "scan":
+                if preds is None or len(set(preds)) == 1:
+                    report = self._probe_scan(
+                        table, queries, k, snapshot_id, pred=preds[0] if preds else None
+                    )
+                else:
+                    report = self._grouped_filtered(
+                        lambda q, p: self._probe_scan(table, q, k, snapshot_id, pred=p),
+                        queries,
+                        preds,
+                    )
+                report.batch_size = B
+                return report
+            meta, snap, puffin_path, reader = self._resolve_index(
+                table_name, snapshot_id, as_of_ms
             )
-        self._apply_tail_report(report, snap, full_tail, served=tail is not None)
-        report.batch_size = B
-        report.snapshot_id = snap.snapshot_id
-        return report
+            full_tail = self._resolve_tail(snap)
+            tail = full_tail if include_tail else None
+            routing = decode_routing_blob(reader.read_first(ROUTING_BLOB_TYPE))
+            shard_blobs = reader.blobs_of_type(SHARD_BLOB_TYPE)
+            strategy = self._choose_strategy(strategy, routing, shard_blobs)
+            if replay_plan is not None and strategy != "diskann":
+                raise ValueError(f"replay_plan is not supported for strategy={strategy!r}")
+            if strategy == "centroid":
+                if preds is None or len(set(preds)) == 1:
+                    report = self._probe_centroid_batch(
+                        table, reader, queries, k, n_probe,
+                        pred=preds[0] if preds else None, puffin_path=puffin_path,
+                        tail=tail,
+                    )
+                else:
+                    # per-group batches keep per-query file ownership, so mixed
+                    # filters still return exactly the sequential probes' hits
+                    report = self._grouped_filtered(
+                        lambda q, p: self._probe_centroid_batch(
+                            table, reader, q, k, n_probe, pred=p,
+                            puffin_path=puffin_path, tail=tail,
+                        ),
+                        queries,
+                        preds,
+                    )
+            else:
+                report = self._probe_diskann_batch(
+                    table,
+                    routing,
+                    reader,
+                    puffin_path,
+                    queries,
+                    k,
+                    use_pq=use_pq,
+                    L=L,
+                    n_route=n_route,
+                    preds=preds,
+                    zonemap=(
+                        self._read_zonemap(reader, puffin_path)
+                        if preds and replay_plan is None
+                        else None
+                    ),
+                    tail=tail,
+                    scan_dtype=scan_dtype,
+                    oversample_override=oversample,
+                    replay_plan=replay_plan,
+                    cache_ctx=(
+                        (table_name, snap.snapshot_id)
+                        if self.probe_cache is not None
+                        else None
+                    ),
+                )
+            self._apply_tail_report(report, snap, full_tail, served=tail is not None)
+            report.batch_size = B
+            report.snapshot_id = snap.snapshot_id
+            return report
 
     def _coerce_filters_batch(
         self, filter: Optional[object], batch_size: int
@@ -1077,13 +1078,14 @@ class Coordinator:
         With ``pred`` this is the brute-force post-filter oracle: every
         passing row is exact-ranked, so the result is the true filtered
         top-k."""
-        t0 = time.time()
-        files = [f.path for f in table.current_files(snapshot_id)]
-        masks, _ = self._filtered_masks(table, files, pred)
-        report = self._rerank_and_merge(table, masks, queries, k, "l2")
+        with timed("coordinator.stage_b") as stage_b:
+            files = [f.path for f in table.current_files(snapshot_id)]
+            masks, _ = self._filtered_masks(table, files, pred)
+            reranked = self._rerank(masks, queries, "l2")
+        report = self._merge(reranked, queries.shape[0], k)
         report.strategy = "scan"
         report.files_scanned = len(files)
-        report.stage_b_seconds = time.time() - t0
+        report.stage_b_seconds = stage_b.seconds
         report.bytes_read = self.store.metrics.bytes_read
         report.filtered = pred is not None
         return report
@@ -1105,25 +1107,24 @@ class Coordinator:
         (when the index carries one) skips row groups that cannot match.
         Fresh-tail files (appended since the index's base snapshot — the
         centroid index has never seen them) join every query's file list."""
-        t0 = time.time()
-        ci = CentroidIndex.from_blob(reader.read_first(CENTROID_BLOB_TYPE))
-        pruned: List[str] = []
-        per_query_files: List[List[str]] = []
-        for q in queries:
-            fl = ci.probe_topk(q, n_probe)
-            per_query_files.append(fl)
-            pruned.extend(fl)
-        if tail is not None:
-            pruned.extend(e.file_path for e in tail.entries)
-        pruned = sorted(set(pruned))
-        stage_a = time.time() - t0
-        zonemap = self._read_zonemap(reader, puffin_path) if pred is not None else None
-        masks, rg_pruned = self._filtered_masks(table, pruned, pred, zonemap)
-        report = self._rerank_and_merge(table, masks, queries, k, ci.metric)
+        with timed("coordinator.stage_a") as stage_a:
+            ci = CentroidIndex.from_blob(reader.read_first(CENTROID_BLOB_TYPE))
+            pruned: List[str] = []
+            for q in queries:
+                pruned.extend(ci.probe_topk(q, n_probe))
+            if tail is not None:
+                pruned.extend(e.file_path for e in tail.entries)
+            pruned = sorted(set(pruned))
+        with timed("coordinator.stage_b") as stage_b:
+            zonemap = self._read_zonemap(reader, puffin_path) if pred is not None else None
+            masks, rg_pruned = self._filtered_masks(table, pruned, pred, zonemap)
+            reranked = self._rerank(masks, queries, ci.metric)
+        report = self._merge(reranked, queries.shape[0], k)
         report.strategy = "centroid"
         report.files_scanned = len(pruned)
         report.plan = self._tail_only_plan(tail, k, queries.shape[0])
-        report.stage_a_seconds = stage_a
+        report.stage_a_seconds = stage_a.seconds
+        report.stage_b_seconds = stage_b.seconds
         report.bytes_read = self.store.metrics.bytes_read
         report.filtered = pred is not None
         report.row_groups_pruned = rg_pruned
@@ -1146,28 +1147,28 @@ class Coordinator:
         result set identical to its sequential probe.  ``pred`` (shared by
         the whole batch on this path) restricts masks to passing rows.
         Fresh-tail files are owned by every query of the batch."""
-        t0 = time.time()
-        ci = CentroidIndex.from_blob(reader.read_first(CENTROID_BLOB_TYPE))
-        per_query_files = ci.probe_topk_batch(queries, n_probe)
-        file_owners: Dict[str, set] = {}
-        for qi, fl in enumerate(per_query_files):
-            for fp in fl:
-                file_owners.setdefault(fp, set()).add(qi)
-        if tail is not None:
-            everyone = set(range(queries.shape[0]))
-            for e in tail.entries:
-                file_owners.setdefault(e.file_path, set()).update(everyone)
-        pruned = sorted(file_owners)
-        stage_a = time.time() - t0
-        zonemap = self._read_zonemap(reader, puffin_path) if pred is not None else None
-        masks, rg_pruned = self._filtered_masks(table, pruned, pred, zonemap)
-        report = self._rerank_and_merge(
-            table, masks, queries, k, ci.metric, file_owners=file_owners
-        )
+        with timed("coordinator.stage_a") as stage_a:
+            ci = CentroidIndex.from_blob(reader.read_first(CENTROID_BLOB_TYPE))
+            per_query_files = ci.probe_topk_batch(queries, n_probe)
+            file_owners: Dict[str, set] = {}
+            for qi, fl in enumerate(per_query_files):
+                for fp in fl:
+                    file_owners.setdefault(fp, set()).add(qi)
+            if tail is not None:
+                everyone = set(range(queries.shape[0]))
+                for e in tail.entries:
+                    file_owners.setdefault(e.file_path, set()).update(everyone)
+            pruned = sorted(file_owners)
+        with timed("coordinator.stage_b") as stage_b:
+            zonemap = self._read_zonemap(reader, puffin_path) if pred is not None else None
+            masks, rg_pruned = self._filtered_masks(table, pruned, pred, zonemap)
+            reranked = self._rerank(masks, queries, ci.metric, file_owners=file_owners)
+        report = self._merge(reranked, queries.shape[0], k)
         report.strategy = "centroid"
         report.files_scanned = len(pruned)
         report.plan = self._tail_only_plan(tail, k, queries.shape[0])
-        report.stage_a_seconds = stage_a
+        report.stage_a_seconds = stage_a.seconds
+        report.stage_b_seconds = stage_b.seconds
         report.bytes_read = self.store.metrics.bytes_read
         report.filtered = pred is not None
         report.row_groups_pruned = rg_pruned
@@ -1232,78 +1233,79 @@ class Coordinator:
                 pruned_shards=tuple(pruned),
             )
         # ---- Stage A: parallel shard beam search -------------------------
-        t0 = time.time()
-        blob_by_index = {i: b for i, b in enumerate(PuffinReader(
-            self.store.stat(puffin_path).size, self.store.range_reader(puffin_path)
-        ).blobs)}
-        tasks = []
-        for s in routing.shards:
-            if pred is not None and s.shard_id not in ops:
-                continue  # zone-pruned
-            b = blob_by_index[s.blob_index]
-            tasks.append(
-                F.ProbeTaskInfo(
-                    task_id=f"probe-{s.shard_id}",
-                    cache_key=f"{puffin_path}#shard{s.shard_id}",
-                    shard_id=s.shard_id,
-                    puffin_path=puffin_path,
-                    blob_offset=b.offset,
-                    blob_length=b.length,
-                    blob_codec=b.compression_codec,
-                    queries=queries,
-                    k=k,
-                    L=L_eff,
-                    use_pq=use_pq,
-                    oversample=oversample,
-                    predicate=pred,
-                    plan_op=ops.get(s.shard_id),
+        with timed("coordinator.stage_a") as stage_a:
+            blob_by_index = {i: b for i, b in enumerate(PuffinReader(
+                self.store.stat(puffin_path).size, self.store.range_reader(puffin_path)
+            ).blobs)}
+            tasks = []
+            for s in routing.shards:
+                if pred is not None and s.shard_id not in ops:
+                    continue  # zone-pruned
+                b = blob_by_index[s.blob_index]
+                tasks.append(
+                    F.ProbeTaskInfo(
+                        task_id=f"probe-{s.shard_id}",
+                        cache_key=f"{puffin_path}#shard{s.shard_id}",
+                        shard_id=s.shard_id,
+                        puffin_path=puffin_path,
+                        blob_offset=b.offset,
+                        blob_length=b.length,
+                        blob_codec=b.compression_codec,
+                        queries=queries,
+                        k=k,
+                        L=L_eff,
+                        use_pq=use_pq,
+                        oversample=oversample,
+                        predicate=pred,
+                        plan_op=ops.get(s.shard_id),
+                    )
                 )
+            Q = queries.shape[0]
+            tail_tasks = self._tail_tasks(
+                tail_list,
+                tail_ops,
+                queries,
+                np.arange(Q, dtype=np.int64),
+                k=k,
+                oversample=oversample,
+                metric=routing.metric,
+                filters=[pred] * Q if pred is not None else None,
             )
-        Q = queries.shape[0]
-        tail_tasks = self._tail_tasks(
-            tail_list,
-            tail_ops,
-            queries,
-            np.arange(Q, dtype=np.int64),
-            k=k,
-            oversample=oversample,
-            metric=routing.metric,
-            filters=[pred] * Q if pred is not None else None,
-        )
-        results = self.scheduler.run_wave(tasks + tail_tasks)
-        probe_results: List[F.ProbeResult] = results[: len(tasks)]
-        tail_results: List[F.BatchProbeResult] = results[len(tasks):]
-        stage_a = time.time() - t0
+            results = self.scheduler.run_wave(tasks + tail_tasks)
+            probe_results: List[F.ProbeResult] = results[: len(tasks)]
+            tail_results: List[F.BatchProbeResult] = results[len(tasks):]
         # ---- merge + Stage B: exact rerank on row-group masks ---------------
-        t1 = time.time()
-        keep = k * oversample
-        merged: List[List[F.ProbeCandidate]] = []
-        for qi in range(Q):
-            cands: List[F.ProbeCandidate] = []
-            for r in probe_results:
-                cands.extend(r.candidates[qi])
-            for r in tail_results:
-                cands.extend(r.candidates.get(qi, []))
-            cands.sort(key=lambda c: c.approx_distance)
-            merged.append(cands[:keep])
-        masks: Dict[str, Dict[int, set]] = {}
-        for qi in range(Q):
-            for c in merged[qi]:
-                masks.setdefault(c.file_path, {}).setdefault(c.row_group, set()).add(
-                    c.row_offset
-                )
-        masks_l = {
-            fp: {rg: sorted(rows) for rg, rows in groups.items()}
-            for fp, groups in masks.items()
-        }
-        report = self._rerank_and_merge(table, masks_l, queries, k, routing.metric)
+        with timed("coordinator.stage_b") as stage_b:
+            with span("coordinator.merge"):
+                keep = k * oversample
+                merged: List[List[F.ProbeCandidate]] = []
+                for qi in range(Q):
+                    cands: List[F.ProbeCandidate] = []
+                    for r in probe_results:
+                        cands.extend(r.candidates[qi])
+                    for r in tail_results:
+                        cands.extend(r.candidates.get(qi, []))
+                    cands.sort(key=lambda c: c.approx_distance)
+                    merged.append(cands[:keep])
+                masks: Dict[str, Dict[int, set]] = {}
+                for qi in range(Q):
+                    for c in merged[qi]:
+                        masks.setdefault(c.file_path, {}).setdefault(c.row_group, set()).add(
+                            c.row_offset
+                        )
+                masks_l = {
+                    fp: {rg: sorted(rows) for rg, rows in groups.items()}
+                    for fp, groups in masks.items()
+                }
+            reranked = self._rerank(masks_l, queries, routing.metric)
+        report = self._merge(reranked, Q, k)
         report.strategy = "diskann"
         report.served_by = [
             f"probe:{r.shard_id}@{r.executor_id}" for r in results
         ] + report.served_by
         report.files_scanned = len(masks_l)
-        report.stage_a_seconds = stage_a
-        report.stage_b_seconds = time.time() - t1 - report.stage_c_seconds
+        report.stage_a_seconds = stage_a.seconds
+        report.stage_b_seconds = stage_b.seconds
         report.shards_probed = len(tasks)
         report.cache_hits = sum(1 for r in probe_results if r.cache_hit)
         report.kernel_dispatches = sum(r.kernel_dispatches for r in results)
@@ -1448,212 +1450,213 @@ class Coordinator:
         if use_pq is None:
             use_pq = int(routing.params.get("pq_m", "0")) > 0
         L_eff = L or int(routing.params.get("L", "100"))
-        t0 = time.time()
-        # the already-open reader has the footer parsed — no re-read
-        blob_by_index = dict(enumerate(reader.blobs))
-        route = self._route_queries(routing, queries, n_route)
-        B = queries.shape[0]
-        # replay: the op grid is taken as-is (shard ops only — synthetic
-        # negative tail ids are dropped; the tail is re-planned below)
-        replay_ops: List[Dict[int, PlanOp]] = (
-            [{sid: op for sid, op in row.items() if sid >= 0} for row in replay_plan.ops]
-            if replay_plan is not None
-            else []
-        )
-        # one plan per distinct predicate; shared across its queries
-        plans: Dict[Predicate, Tuple[Dict[int, PlanOp], List[int], float]] = {}
-        if preds and replay_plan is None:
-            for p in preds:
-                if p is not None and p not in plans:
-                    plans[p] = planner.plan_filtered(
-                        p, zonemap, routing,
-                        k=k, oversample=oversample, use_pq=use_pq,
-                        scan_dtype=scan_dtype,
-                    )
-        # pre-pass: which shards end up with MIXED fragments (filtered and
-        # unfiltered queries coalesced together)?  An unfiltered query on a
-        # mixed shard needs a planner op of its own — a shared beam, or a
-        # size-capped all-ones exact row on small shards — instead of the
-        # old uncapped O(N·D) all-ones scan.
-        shard_filtered: Dict[int, bool] = {}
-        shard_unfiltered: Dict[int, bool] = {}
-        if replay_plan is None:
+        with timed("coordinator.stage_a") as stage_a:
+            # the already-open reader has the footer parsed — no re-read
+            blob_by_index = dict(enumerate(reader.blobs))
+            route = self._route_queries(routing, queries, n_route)
+            B = queries.shape[0]
+            # replay: the op grid is taken as-is (shard ops only — synthetic
+            # negative tail ids are dropped; the tail is re-planned below)
+            replay_ops: List[Dict[int, PlanOp]] = (
+                [{sid: op for sid, op in row.items() if sid >= 0} for row in replay_plan.ops]
+                if replay_plan is not None
+                else []
+            )
+            # one plan per distinct predicate; shared across its queries
+            plans: Dict[Predicate, Tuple[Dict[int, PlanOp], List[int], float]] = {}
+            if preds and replay_plan is None:
+                for p in preds:
+                    if p is not None and p not in plans:
+                        plans[p] = planner.plan_filtered(
+                            p, zonemap, routing,
+                            k=k, oversample=oversample, use_pq=use_pq,
+                            scan_dtype=scan_dtype,
+                        )
+            # pre-pass: which shards end up with MIXED fragments (filtered and
+            # unfiltered queries coalesced together)?  An unfiltered query on a
+            # mixed shard needs a planner op of its own — a shared beam, or a
+            # size-capped all-ones exact row on small shards — instead of the
+            # old uncapped O(N·D) all-ones scan.
+            shard_filtered: Dict[int, bool] = {}
+            shard_unfiltered: Dict[int, bool] = {}
+            if replay_plan is None:
+                for s in routing.shards:
+                    for qi in range(B):
+                        if s.shard_id not in route[qi]:
+                            continue
+                        pred = preds[qi] if preds else None
+                        if pred is None:
+                            shard_unfiltered[s.shard_id] = True
+                        elif s.shard_id in plans[pred][0]:
+                            shard_filtered[s.shard_id] = True
+            fragments_pruned = 0
+            ops_grid: List[Dict[int, PlanOp]] = [dict() for _ in range(B)]
+            tasks: List[F.BatchProbeTaskInfo] = []
+            # cross-batch shard-probe cache (serving/cache.py): keys carry the
+            # snapshot id, predicate, search params, plan op, and the exact
+            # query bytes, so a hit replays the identical Stage-A fragment
+            cache = self.probe_cache if cache_ctx is not None else None
+            q_digests: List[bytes] = (
+                [query_digest(queries[qi]) for qi in range(B)] if cache is not None else []
+            )
+            cached: Dict[Tuple[int, int], List[F.ProbeCandidate]] = {}
+            cache_puts: List[Tuple[tuple, int, int]] = []  # (key, qi, shard_id)
             for s in routing.shards:
+                b = blob_by_index[s.blob_index]
+                mixed = shard_filtered.get(s.shard_id, False) and shard_unfiltered.get(
+                    s.shard_id, False
+                )
                 for qi in range(B):
                     if s.shard_id not in route[qi]:
                         continue
                     pred = preds[qi] if preds else None
-                    if pred is None:
-                        shard_unfiltered[s.shard_id] = True
-                    elif s.shard_id in plans[pred][0]:
-                        shard_filtered[s.shard_id] = True
-        fragments_pruned = 0
-        ops_grid: List[Dict[int, PlanOp]] = [dict() for _ in range(B)]
-        tasks: List[F.BatchProbeTaskInfo] = []
-        # cross-batch shard-probe cache (serving/cache.py): keys carry the
-        # snapshot id, predicate, search params, plan op, and the exact
-        # query bytes, so a hit replays the identical Stage-A fragment
-        cache = self.probe_cache if cache_ctx is not None else None
-        q_digests: List[bytes] = (
-            [query_digest(queries[qi]) for qi in range(B)] if cache is not None else []
-        )
-        cached: Dict[Tuple[int, int], List[F.ProbeCandidate]] = {}
-        cache_puts: List[Tuple[tuple, int, int]] = []  # (key, qi, shard_id)
-        for s in routing.shards:
-            b = blob_by_index[s.blob_index]
-            mixed = shard_filtered.get(s.shard_id, False) and shard_unfiltered.get(
-                s.shard_id, False
+                    op: Optional[PlanOp] = None
+                    if replay_plan is not None:
+                        op = replay_ops[qi].get(s.shard_id)
+                        if isinstance(op, planner.Skip):
+                            fragments_pruned += 1
+                            ops_grid[qi][s.shard_id] = op
+                            continue  # the replayed plan pruned this fragment
+                    elif pred is not None:
+                        shard_ops, _pruned, _frac = plans[pred]
+                        if s.shard_id not in shard_ops:
+                            fragments_pruned += 1
+                            ops_grid[qi][s.shard_id] = planner.Skip()
+                            continue  # zone-pruned for this query's predicate
+                        op = shard_ops[s.shard_id]
+                    elif plans:
+                        op = planner.plan_unfiltered(
+                            s.vector_count, mixed=mixed, k=k, oversample=oversample
+                        )
+                    if op is not None:
+                        ops_grid[qi][s.shard_id] = op
+                    if cache is not None:
+                        ckey = (
+                            cache_ctx[0],
+                            cache_ctx[1],
+                            s.shard_id,
+                            pred,
+                            (k, L_eff, use_pq, oversample),
+                            op,
+                            q_digests[qi],
+                        )
+                        ent = cache.get(ckey)
+                        if ent is not None:
+                            # Stage-A hit: skip mask evaluation and the kernel
+                            # dispatch for this fragment; the cached candidates
+                            # re-merge below in this shard's routing slot
+                            cached[(qi, s.shard_id)] = ent.candidates
+                            continue
+                        cache_puts.append((ckey, qi, s.shard_id))
+                    tasks.append(
+                        F.BatchProbeTaskInfo(
+                            task_id=f"probe-{s.shard_id}-q{qi}",
+                            cache_key=f"{puffin_path}#shard{s.shard_id}",
+                            shard_id=s.shard_id,
+                            puffin_path=puffin_path,
+                            blob_offset=b.offset,
+                            blob_length=b.length,
+                            blob_codec=b.compression_codec,
+                            queries=queries[qi : qi + 1],
+                            query_index=np.array([qi], np.int64),
+                            k=k,
+                            L=L_eff,
+                            use_pq=use_pq,
+                            oversample=oversample,
+                            filters=[pred] if pred is not None else None,
+                            plan_ops=[op] if op is not None else None,
+                        )
+                    )
+            # fresh-tail fragments: every query scans every tail row group (tail
+            # rows are outside the routing table, so n_route cannot skip them)
+            tail_list = tail.row_group_list() if tail is not None else []
+            tail_ops: Dict[int, PlanOp] = (
+                planner.plan_tail(
+                    [cnt for _, _, cnt in tail_list], k=k, oversample=oversample
+                )
+                if tail_list
+                else {}
             )
             for qi in range(B):
-                if s.shard_id not in route[qi]:
-                    continue
-                pred = preds[qi] if preds else None
-                op: Optional[PlanOp] = None
-                if replay_plan is not None:
-                    op = replay_ops[qi].get(s.shard_id)
-                    if isinstance(op, planner.Skip):
-                        fragments_pruned += 1
-                        ops_grid[qi][s.shard_id] = op
-                        continue  # the replayed plan pruned this fragment
-                elif pred is not None:
-                    shard_ops, _pruned, _frac = plans[pred]
-                    if s.shard_id not in shard_ops:
-                        fragments_pruned += 1
-                        ops_grid[qi][s.shard_id] = planner.Skip()
-                        continue  # zone-pruned for this query's predicate
-                    op = shard_ops[s.shard_id]
-                elif plans:
-                    op = planner.plan_unfiltered(
-                        s.vector_count, mixed=mixed, k=k, oversample=oversample
-                    )
-                if op is not None:
-                    ops_grid[qi][s.shard_id] = op
-                if cache is not None:
-                    ckey = (
-                        cache_ctx[0],
-                        cache_ctx[1],
-                        s.shard_id,
-                        pred,
-                        (k, L_eff, use_pq, oversample),
-                        op,
-                        q_digests[qi],
-                    )
-                    ent = cache.get(ckey)
-                    if ent is not None:
-                        # Stage-A hit: skip mask evaluation and the kernel
-                        # dispatch for this fragment; the cached candidates
-                        # re-merge below in this shard's routing slot
-                        cached[(qi, s.shard_id)] = ent.candidates
-                        continue
-                    cache_puts.append((ckey, qi, s.shard_id))
-                tasks.append(
-                    F.BatchProbeTaskInfo(
-                        task_id=f"probe-{s.shard_id}-q{qi}",
-                        cache_key=f"{puffin_path}#shard{s.shard_id}",
-                        shard_id=s.shard_id,
-                        puffin_path=puffin_path,
-                        blob_offset=b.offset,
-                        blob_length=b.length,
-                        blob_codec=b.compression_codec,
-                        queries=queries[qi : qi + 1],
-                        query_index=np.array([qi], np.int64),
-                        k=k,
-                        L=L_eff,
-                        use_pq=use_pq,
-                        oversample=oversample,
-                        filters=[pred] if pred is not None else None,
-                        plan_ops=[op] if op is not None else None,
-                    )
-                )
-        # fresh-tail fragments: every query scans every tail row group (tail
-        # rows are outside the routing table, so n_route cannot skip them)
-        tail_list = tail.row_group_list() if tail is not None else []
-        tail_ops: Dict[int, PlanOp] = (
-            planner.plan_tail(
-                [cnt for _, _, cnt in tail_list], k=k, oversample=oversample
+                ops_grid[qi].update(tail_ops)
+            tail_tasks = self._tail_tasks(
+                tail_list,
+                tail_ops,
+                queries,
+                np.arange(B, dtype=np.int64),
+                k=k,
+                oversample=oversample,
+                metric=routing.metric,
+                filters=preds,
             )
-            if tail_list
-            else {}
-        )
-        for qi in range(B):
-            ops_grid[qi].update(tail_ops)
-        tail_tasks = self._tail_tasks(
-            tail_list,
-            tail_ops,
-            queries,
-            np.arange(B, dtype=np.int64),
-            k=k,
-            oversample=oversample,
-            metric=routing.metric,
-            filters=preds,
-        )
-        results: List[F.BatchProbeResult] = self.scheduler.run_coalesced_wave(
-            tasks + tail_tasks
-        )
-        # coalescing preserves first-appearance order, so the tail fragments
-        # (appended last, never merged) are the trailing results
-        n_shard_results = len(results) - len(tail_tasks)
-        probe_results = results[:n_shard_results]
-        tail_results = results[n_shard_results:]
-        by_shard = {r.shard_id: r for r in probe_results}
-        if cache is not None:
-            for ckey, qi, sid in cache_puts:
-                r = by_shard.get(sid)
-                if r is not None:
-                    cache.put(
-                        ckey,
-                        r.candidates.get(qi, []),
-                        table_name=cache_ctx[0],
-                        snapshot_id=cache_ctx[1],
-                        served_by=r.executor_id,
-                    )
-        stage_a = time.time() - t0
-        # ---- merge + Stage B: exact rerank with per-row ownership ----------
-        t1 = time.time()
-        keep = k * oversample
-        merged: List[List[F.ProbeCandidate]] = []
-        for qi in range(B):
-            cands: List[F.ProbeCandidate] = []
-            # routing order (== uncached result order): a cache hit drops
-            # its candidates into exactly the slot the live fragment would
-            # have filled, so the stable sort below ties-break identically
-            # and the final hits are bit-identical to the uncached path
-            for s in routing.shards:
-                hit = cached.get((qi, s.shard_id))
-                if hit is not None:
-                    cands.extend(hit)
-                else:
-                    r = by_shard.get(s.shard_id)
+            results: List[F.BatchProbeResult] = self.scheduler.run_coalesced_wave(
+                tasks + tail_tasks
+            )
+            # coalescing preserves first-appearance order, so the tail fragments
+            # (appended last, never merged) are the trailing results
+            n_shard_results = len(results) - len(tail_tasks)
+            probe_results = results[:n_shard_results]
+            tail_results = results[n_shard_results:]
+            by_shard = {r.shard_id: r for r in probe_results}
+            if cache is not None:
+                for ckey, qi, sid in cache_puts:
+                    r = by_shard.get(sid)
                     if r is not None:
+                        cache.put(
+                            ckey,
+                            r.candidates.get(qi, []),
+                            table_name=cache_ctx[0],
+                            snapshot_id=cache_ctx[1],
+                            served_by=r.executor_id,
+                        )
+        # ---- merge + Stage B: exact rerank with per-row ownership ----------
+        with timed("coordinator.stage_b") as stage_b:
+            with span("coordinator.merge"):
+                keep = k * oversample
+                merged: List[List[F.ProbeCandidate]] = []
+                for qi in range(B):
+                    cands: List[F.ProbeCandidate] = []
+                    # routing order (== uncached result order): a cache hit drops
+                    # its candidates into exactly the slot the live fragment would
+                    # have filled, so the stable sort below ties-break identically
+                    # and the final hits are bit-identical to the uncached path
+                    for s in routing.shards:
+                        hit = cached.get((qi, s.shard_id))
+                        if hit is not None:
+                            cands.extend(hit)
+                        else:
+                            r = by_shard.get(s.shard_id)
+                            if r is not None:
+                                cands.extend(r.candidates.get(qi, []))
+                    for r in tail_results:  # tail fragments merge last, as dispatched
                         cands.extend(r.candidates.get(qi, []))
-            for r in tail_results:  # tail fragments merge last, as dispatched
-                cands.extend(r.candidates.get(qi, []))
-            cands.sort(key=lambda c: c.approx_distance)
-            merged.append(cands[:keep])
-        masks: Dict[str, Dict[int, set]] = {}
-        row_owners: Dict[str, Dict[int, Dict[int, set]]] = {}
-        for qi in range(B):
-            for c in merged[qi]:
-                masks.setdefault(c.file_path, {}).setdefault(c.row_group, set()).add(
-                    c.row_offset
-                )
-                row_owners.setdefault(c.file_path, {}).setdefault(
-                    c.row_group, {}
-                ).setdefault(c.row_offset, set()).add(qi)
-        masks_l = {
-            fp: {rg: sorted(rows) for rg, rows in groups.items()}
-            for fp, groups in masks.items()
-        }
-        report = self._rerank_and_merge(
-            table, masks_l, queries, k, routing.metric, row_owners=row_owners
-        )
+                    cands.sort(key=lambda c: c.approx_distance)
+                    merged.append(cands[:keep])
+                masks: Dict[str, Dict[int, set]] = {}
+                row_owners: Dict[str, Dict[int, Dict[int, set]]] = {}
+                for qi in range(B):
+                    for c in merged[qi]:
+                        masks.setdefault(c.file_path, {}).setdefault(c.row_group, set()).add(
+                            c.row_offset
+                        )
+                        row_owners.setdefault(c.file_path, {}).setdefault(
+                            c.row_group, {}
+                        ).setdefault(c.row_offset, set()).add(qi)
+                masks_l = {
+                    fp: {rg: sorted(rows) for rg, rows in groups.items()}
+                    for fp, groups in masks.items()
+                }
+            reranked = self._rerank(
+                masks_l, queries, routing.metric, row_owners=row_owners
+            )
+        report = self._merge(reranked, B, k)
         report.strategy = "diskann"
         report.served_by = [
             f"probe:{r.shard_id}@{r.executor_id}" for r in results
         ] + report.served_by
         report.files_scanned = len(masks_l)
-        report.stage_a_seconds = stage_a
-        report.stage_b_seconds = time.time() - t1 - report.stage_c_seconds
+        report.stage_a_seconds = stage_a.seconds
+        report.stage_b_seconds = stage_b.seconds
         report.shards_probed = len(probe_results)
         report.probe_fragments = len(probe_results)
         report.cache_hits = sum(1 for r in probe_results if r.cache_hit)
@@ -1694,17 +1697,16 @@ class Coordinator:
             )
         return report
 
-    def _rerank_and_merge(
+    def _rerank(
         self,
-        table: LakehouseTable,
         masks: Dict[str, Dict[int, List[int]]],
         queries: np.ndarray,
-        k: int,
         metric: str,
         file_owners: Optional[Dict[str, set]] = None,
         row_owners: Optional[Dict[str, Dict[int, Dict[int, set]]]] = None,
-    ) -> ProbeReport:
-        """Stage B (parallel rerank) + Stage C (ordered merge).
+    ) -> List[F.RerankResult]:
+        """Stage B's wave: the masked rows, spread over the live executors
+        by file, each read and scored once.
 
         ``file_owners`` / ``row_owners`` carry batched-probe ownership: each
         query's Stage-C merge sees only the rows it routed to, even though
@@ -1736,26 +1738,29 @@ class Coordinator:
                     ),
                 )
             )
-        results: List[F.RerankResult] = self.scheduler.run_wave(tasks) if tasks else []
-        # Stage C: streaming loser-tree merge (here: heap merge per query)
-        t2 = time.time()
-        Q = queries.shape[0]
-        hits: List[List[ProbeHit]] = []
-        for qi in range(Q):
-            rows = []
-            for r in results:
-                rows.extend(r.rows[qi])
-            best = heapq.nsmallest(k, rows, key=lambda x: x.distance)
-            hits.append(
-                [ProbeHit(b.file_path, b.row_group, b.row_offset, b.distance) for b in best]
-            )
-        stage_c = time.time() - t2
+        return self.scheduler.run_wave(tasks) if tasks else []
+
+    @staticmethod
+    def _merge(results: List[F.RerankResult], num_queries: int, k: int) -> ProbeReport:
+        """Stage C, as the ``coordinator.stage_c`` span: each query's k
+        nearest reranked rows (a heap merge standing in for the streaming
+        loser tree)."""
+        with timed("coordinator.stage_c") as stage_c:
+            hits: List[List[ProbeHit]] = []
+            for qi in range(num_queries):
+                rows = []
+                for r in results:
+                    rows.extend(r.rows[qi])
+                best = heapq.nsmallest(k, rows, key=lambda x: x.distance)
+                hits.append(
+                    [ProbeHit(b.file_path, b.row_group, b.row_offset, b.distance) for b in best]
+                )
         return ProbeReport(
             hits=hits,
             strategy="",
             files_scanned=0,
             bytes_read=0,
-            stage_c_seconds=stage_c,
+            stage_c_seconds=stage_c.seconds,
             served_by=[f"rerank@{r.executor_id}" for r in results],
         )
 
@@ -1841,7 +1846,6 @@ class Coordinator:
                         vector_count=r.vector_count,
                         byte_size=r.byte_size,
                         executor_id=r.executor_id,
-                        build_seconds=r.refresh_seconds,
                         rg_membership=r.rg_membership,
                     )
                 )

@@ -33,6 +33,7 @@ from repro.kernels import device_cache, ops
 from repro.lakehouse.objectstore import ObjectStore
 from repro.lakehouse.vparquet import VParquetReader
 from repro.runtime import fragments as F
+from repro.serving.metrics import span
 
 import jax.numpy as jnp
 
@@ -243,27 +244,28 @@ class Executor:
     def _load_shard(
         self, puffin_path: str, offset: int, length: int, codec: Optional[str], cache_key: Optional[str]
     ) -> Tuple[VamanaGraph, ShardLocationMap, bool]:
-        l1_key = f"{cache_key or puffin_path}@{offset}"
-        with self._lock:
-            if l1_key in self._l1:
-                self._l1.move_to_end(l1_key)
-                self.cache_hits += 1
-                g, lm = self._l1[l1_key]
-                return g, lm, True
-        raw, hit = self.fetch_range_cached(puffin_path, offset, length)
-        payload = _decompress(codec, raw)
-        graph, locmap = decode_shard_blob(payload, lazy_vectors=True)
-        if not np.any(graph.vectors[: graph.n]):
-            # lean blob (paper §4.3 retention policy): full-precision vectors
-            # omitted — re-fetch them from Parquet through the location map
-            # (the "extra round trip" trade-off), then L1-cache as usual.
-            graph.vectors[: graph.n] = self._fetch_vectors(locmap, graph.n)
-        with self._lock:
-            self._l1[l1_key] = (graph, locmap)
-            while len(self._l1) > self._l1_capacity:
-                self._l1.popitem(last=False)
-        self._mark_cached(cache_key)
-        return graph, locmap, hit
+        with span("executor.load_shard"):
+            l1_key = f"{cache_key or puffin_path}@{offset}"
+            with self._lock:
+                if l1_key in self._l1:
+                    self._l1.move_to_end(l1_key)
+                    self.cache_hits += 1
+                    g, lm = self._l1[l1_key]
+                    return g, lm, True
+            raw, hit = self.fetch_range_cached(puffin_path, offset, length)
+            payload = _decompress(codec, raw)
+            graph, locmap = decode_shard_blob(payload, lazy_vectors=True)
+            if not np.any(graph.vectors[: graph.n]):
+                # lean blob (paper §4.3 retention policy): full-precision vectors
+                # omitted — re-fetch them from Parquet through the location map
+                # (the "extra round trip" trade-off), then L1-cache as usual.
+                graph.vectors[: graph.n] = self._fetch_vectors(locmap, graph.n)
+            with self._lock:
+                self._l1[l1_key] = (graph, locmap)
+                while len(self._l1) > self._l1_capacity:
+                    self._l1.popitem(last=False)
+            self._mark_cached(cache_key)
+            return graph, locmap, hit
 
     def _fetch_vectors(self, locmap: ShardLocationMap, n: int) -> np.ndarray:
         """Read each indexed vector's row from its source Parquet row group."""
@@ -772,6 +774,12 @@ class Executor:
 
     # -- dispatch ------------------------------------------------------------
     def handle(self, task) -> object:
+        """Run one fragment, as one ``executor.task`` span."""
+        with span("executor.task", kind=type(task).__name__.removesuffix("TaskInfo"),
+                  shard=getattr(task, "shard_id", None), executor=self.executor_id):
+            return self._handle(task)
+
+    def _handle(self, task) -> object:
         self._gate()
         if self._kill_mid_task > 0:
             self._kill_mid_task -= 1
@@ -821,7 +829,6 @@ class Executor:
         return out
 
     def _build_shard(self, task: F.IndexBuildTaskInfo) -> F.IndexBuildResult:
-        t0 = time.time()
         if task.exchanged is not None:
             vectors, fidx, rgrp, roff, paths = task.exchanged
         else:
@@ -862,7 +869,6 @@ class Executor:
             vector_count=graph.n,
             byte_size=len(blob),
             executor_id=self.executor_id,
-            build_seconds=time.time() - t0,
             partition_counts=counts,
             rg_membership=_locmap_membership(locmap, graph.n),
         )
@@ -885,8 +891,10 @@ class Executor:
         L = max(task.L, k_eff)
         if task.use_pq and graph.pq is not None:
             self._count_graph_reranks(q)
-            return graph.search_pq(q, k_eff, L=L)
-        return graph.search(q, k_eff, L=L)
+            with span("traversal.search_pq"):
+                return graph.search_pq(q, k_eff, L=L)
+        with span("traversal.search"):
+            return graph.search(q, k_eff, L=L)
 
     def _row_candidates(
         self, graph, locmap, dists_row, ids_row, shard_id: int
@@ -909,7 +917,6 @@ class Executor:
         return cands
 
     def _probe_shard(self, task: F.ProbeTaskInfo) -> F.ProbeResult:
-        t0 = time.time()
         graph, locmap, hit = self._load_shard(
             task.puffin_path, task.blob_offset, task.blob_length, task.blob_codec, task.cache_key
         )
@@ -926,11 +933,11 @@ class Executor:
             kernel_dispatches=self._task_dispatches(),
             masked_beam_rows=mb_rows, masked_beam_fallbacks=mb_fb,
         )
-        for qi in range(task.queries.shape[0]):
-            result.candidates.append(
-                self._row_candidates(graph, locmap, dists[qi], ids[qi], task.shard_id)
-            )
-        result.probe_seconds = time.time() - t0
+        with span("executor.candidates"):
+            for qi in range(task.queries.shape[0]):
+                result.candidates.append(
+                    self._row_candidates(graph, locmap, dists[qi], ids[qi], task.shard_id)
+                )
         return result
 
     def _tail_scan(self, task: F.TailScanTaskInfo) -> F.BatchProbeResult:
@@ -942,7 +949,6 @@ class Executor:
         (+inf, -1) sentinel contract covers zero-match predicates and
         k > live-rows exactly as shard scans do — sentinel slots are
         dropped before candidates leave the executor."""
-        t0 = time.time()
         result = F.BatchProbeResult(
             shard_id=task.tail_id, executor_id=self.executor_id
         )
@@ -956,7 +962,6 @@ class Executor:
         if n == 0:
             for qi in qidx:
                 result.candidates[int(qi)] = []
-            result.probe_seconds = time.time() - t0
             return result
         q = np.ascontiguousarray(task.queries, np.float32)
         k_eff = min(max(1, task.k * task.oversample), n)
@@ -1008,7 +1013,6 @@ class Executor:
                 if np.isfinite(dist) and vid >= 0
             ]
         result.kernel_dispatches = self._task_dispatches()
-        result.probe_seconds = time.time() - t0
         return result
 
     def _probe_shard_batch(self, task: F.BatchProbeTaskInfo) -> F.BatchProbeResult:
@@ -1024,7 +1028,6 @@ class Executor:
         small shards, per their planner op.  The legacy per-predicate-group
         loop survives only behind ``force_group_loop`` for parity/bench
         comparison."""
-        t0 = time.time()
         graph, locmap, hit = self._load_shard(
             task.puffin_path, task.blob_offset, task.blob_length, task.blob_codec, task.cache_key
         )
@@ -1037,11 +1040,11 @@ class Executor:
             # fully-unfiltered fragments keep the batched beam search: its
             # hits must stay byte-identical to sequential probe() calls
             dists, ids = self._shard_search(task, graph)
-            for bi, qi in enumerate(qidx):
-                result.candidates[int(qi)] = self._row_candidates(
-                    graph, locmap, dists[bi], ids[bi], task.shard_id
-                )
-            result.probe_seconds = time.time() - t0
+            with span("executor.candidates"):
+                for bi, qi in enumerate(qidx):
+                    result.candidates[int(qi)] = self._row_candidates(
+                        graph, locmap, dists[bi], ids[bi], task.shard_id
+                    )
             return result
         if self.force_group_loop:
             self._probe_groups(task, graph, locmap, result, qidx, range(len(qidx)))
@@ -1049,7 +1052,6 @@ class Executor:
             self._probe_mask_plane(task, graph, locmap, result, qidx)
         result.kernel_dispatches = self._task_dispatches()
         result.masked_beam_rows, result.masked_beam_fallbacks = self._task_mbeam()
-        result.probe_seconds = time.time() - t0
         return result
 
     def _probe_groups(
@@ -1378,49 +1380,54 @@ class Executor:
         return short_rows
 
     def _rerank(self, task: F.RerankTaskInfo) -> F.RerankResult:
+        """Stage B on this executor's files: read the candidate rows
+        (``executor.rerank.read``), score them (``executor.rerank.score``)
+        and emit each query's owned rows (``executor.rerank.emit``)."""
         rows_flat: List[Tuple[str, int, int]] = []
         # per flat row: None => every query owns it, else the owning set
         owners_flat: List[Optional[set]] = []
         vec_parts: List[np.ndarray] = []
-        for fpath, groups in task.masks.items():
-            reader = VParquetReader.from_store(self.store, fpath)
-            f_own = task.file_owners.get(fpath) if task.file_owners else None
-            r_own = task.row_owners.get(fpath) if task.row_owners else None
-            for rg_id, offsets in groups.items():
-                arr = reader.read_rows("vec", rg_id, offsets)
-                vec_parts.append(arr)
-                rg_own = r_own.get(rg_id) if r_own is not None else None
-                for off in offsets:
-                    rows_flat.append((fpath, rg_id, off))
-                    if rg_own is not None:
-                        owners_flat.append(rg_own.get(off, set()))
-                    else:
-                        owners_flat.append(f_own)
+        with span("executor.rerank.read"):
+            for fpath, groups in task.masks.items():
+                reader = VParquetReader.from_store(self.store, fpath)
+                f_own = task.file_owners.get(fpath) if task.file_owners else None
+                r_own = task.row_owners.get(fpath) if task.row_owners else None
+                for rg_id, offsets in groups.items():
+                    arr = reader.read_rows("vec", rg_id, offsets)
+                    vec_parts.append(arr)
+                    rg_own = r_own.get(rg_id) if r_own is not None else None
+                    for off in offsets:
+                        rows_flat.append((fpath, rg_id, off))
+                        if rg_own is not None:
+                            owners_flat.append(rg_own.get(off, set()))
+                        else:
+                            owners_flat.append(f_own)
         result = F.RerankResult(executor_id=self.executor_id)
         q = np.ascontiguousarray(task.queries, np.float32)
         if not rows_flat:
             result.rows = [[] for _ in range(q.shape[0])]
             return result
-        cands = np.concatenate(vec_parts)
         # the union of every query's rows is read and scored ONCE — a single
         # batched kernel call; ownership filters the (Q, N) matrix afterwards
-        d = np.asarray(
-            ops.exact_distances(
-                jnp.asarray(q), jnp.asarray(cands), metric=task.metric, backend="ref"
+        with span("executor.rerank.score"):
+            cands = np.concatenate(vec_parts)
+            d = np.asarray(
+                ops.exact_distances(
+                    jnp.asarray(q), jnp.asarray(cands), metric=task.metric, backend="ref"
+                )
             )
-        )
-        for qi in range(q.shape[0]):
-            result.rows.append(
-                [
-                    F.RerankRow(fp, rg, ro, float(d[qi, ci]))
-                    for ci, (fp, rg, ro) in enumerate(rows_flat)
-                    if owners_flat[ci] is None or qi in owners_flat[ci]
-                ]
-            )
+        with span("executor.rerank.emit"):
+            for qi in range(q.shape[0]):
+                result.rows.append(
+                    [
+                        F.RerankRow(fp, rg, ro, float(d[qi, ci]))
+                        for ci, (fp, rg, ro) in enumerate(rows_flat)
+                        if owners_flat[ci] is None or qi in owners_flat[ci]
+                    ]
+                )
         return result
 
     def _refresh_shard(self, task: F.RefreshTaskInfo) -> F.RefreshResult:
-        t0 = time.time()
         graph, locmap, _hit = self._load_shard(
             task.puffin_path, task.blob_offset, task.blob_length, task.blob_codec, task.cache_key
         )
@@ -1479,7 +1486,6 @@ class Executor:
             vector_count=graph.n,
             byte_size=len(blob),
             tombstone_ratio=graph.tombstone_ratio,
-            refresh_seconds=time.time() - t0,
             rg_membership=_locmap_membership(
                 locmap, graph.n, live=~graph.tombstones[: graph.n]
             ),

@@ -56,7 +56,6 @@ class IndexBuildResult:
     vector_count: int
     byte_size: int
     executor_id: str
-    build_seconds: float
     # per-partition vector counts (routing-table population, paper §5 Stage 1)
     partition_counts: Optional[np.ndarray] = None
     # (file_path, row_group) pairs this shard's vectors came from — the
@@ -123,7 +122,6 @@ class ProbeResult:
     # per query: list of candidates
     candidates: List[List[ProbeCandidate]] = field(default_factory=list)
     cache_hit: bool = False
-    probe_seconds: float = 0.0
     # masked top-k kernel calls this task issued (observability for the
     # heterogeneous-filter coalescing win; 0 on pure beam paths)
     kernel_dispatches: int = 0
@@ -212,7 +210,6 @@ class BatchProbeResult:
     # original batch position -> candidates for that query
     candidates: Dict[int, List[ProbeCandidate]] = field(default_factory=dict)
     cache_hit: bool = False
-    probe_seconds: float = 0.0
     # masked top-k kernel calls this fragment cost: 1 per scoring flavor on
     # the mask-plane path, vs one per distinct predicate on the legacy
     # group loop — the coordinator sums these into
@@ -338,7 +335,6 @@ class RefreshResult:
     vector_count: int
     byte_size: int
     tombstone_ratio: float
-    refresh_seconds: float = 0.0
     # refreshed (file, row_group) membership over LIVE rows, for the
     # rebuilt zone map's shard-pruning table
     rg_membership: Optional[List[Tuple[str, int]]] = None

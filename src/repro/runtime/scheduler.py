@@ -28,6 +28,7 @@ Responsibilities (DESIGN.md §6):
 
 from __future__ import annotations
 
+import contextvars
 import queue
 import threading
 import time
@@ -37,7 +38,7 @@ from typing import Dict, List, Optional
 from repro.runtime import fragments as F
 from repro.runtime.executor import Executor, ExecutorDead, InjectedFailure
 from repro.serving.leases import LeaseTable
-from repro.serving.metrics import MetricsRegistry
+from repro.serving.metrics import MetricsRegistry, span
 
 
 @dataclass
@@ -137,8 +138,14 @@ class Scheduler:
         """Dispatch a wave of fragments; returns results aligned to tasks.
 
         Raises RuntimeError if any task exhausts ``max_attempts`` or the
-        executor pool dies entirely.
+        executor pool dies entirely.  The wave is one ``scheduler.wave``
+        span; each attempt thread runs in a copy of the dispatching context,
+        so its ``executor.task`` span names the wave as parent.
         """
+        with span("scheduler.wave", tasks=len(tasks)):
+            return self._dispatch(tasks)
+
+    def _dispatch(self, tasks: List[object]) -> List[object]:
         n = len(tasks)
         results: List[Optional[object]] = [None] * n
         done = [False] * n
@@ -251,7 +258,9 @@ class Scheduler:
                         break
                     holder: list = []
                     th = threading.Thread(
-                        target=run_one, args=(idx, ex, False, holder), daemon=True
+                        target=contextvars.copy_context().run,
+                        args=(run_one, idx, ex, False, holder),
+                        daemon=True,
                     )
                     att = _Attempt(idx, ex, th, time.time())
                     holder.append(att)
@@ -281,8 +290,8 @@ class Scheduler:
                             if ex is not None and ex is not att.executor:
                                 holder = []
                                 th = threading.Thread(
-                                    target=run_one,
-                                    args=(att.task_index, ex, True, holder),
+                                    target=contextvars.copy_context().run,
+                                    args=(run_one, att.task_index, ex, True, holder),
                                     daemon=True,
                                 )
                                 spec = _Attempt(att.task_index, ex, th, time.time(), True)

@@ -18,6 +18,7 @@ serving tier sees.
 from __future__ import annotations
 
 import functools
+import itertools
 import math
 import queue as queue_mod
 import threading
@@ -43,7 +44,7 @@ from repro.serving.admission import (
     TenantPolicy,
 )
 from repro.serving.device_index import DeviceAnnIndex
-from repro.serving.metrics import MetricsRegistry
+from repro.serving.metrics import MetricsRegistry, span
 
 
 @dataclass
@@ -164,6 +165,15 @@ class ProbeMicroBatcher:
     refused — never served silently late.  Per-tenant latency histograms
     (p50/p99) and decision counters live in ``self.metrics``.
 
+    **Operator metrics.**  ``latency_ms[<tenant>]`` times each served probe
+    from ``submit`` to its answer; ``serving.queue_wait_ms[<tenant>]`` times
+    the part of it spent queued, from ``submit`` to the ``probe_batch`` call
+    that carries the probe.  Their difference is the batch's service time.
+    With tracing on (:func:`repro.serving.metrics.set_tracing`) each
+    ``probe_batch`` call is one ``serving.batch`` span (attributes
+    ``probes``, ``k`` and ``queue_wait_ms``, the batch's mean wait) whose
+    ``trace_id``, the batch's sequence number, every span under it shares.
+
     **Degradation.**  With a :class:`DegradationPolicy` attached, a drain
     under pressure (queue depth vs. capacity, and batch-latency EMA vs. the
     tightest pending deadline) trades answer quality for latency through
@@ -235,6 +245,7 @@ class ProbeMicroBatcher:
         self._stats_lock = threading.Lock()
         self._max_queue = max_queue
         self._latency_ema = 0.0  # EMA of drained-batch service time (s)
+        self._batch_seq = itertools.count(1)  # trace id of each probe_batch call
         self._queue: "queue_mod.Queue" = queue_mod.Queue(maxsize=max_queue or 0)
         self._thread: Optional[threading.Thread] = None
         self._compact_thread: Optional[threading.Thread] = None
@@ -475,15 +486,22 @@ class ProbeMicroBatcher:
                     probe_kwargs["include_tail"] = params.include_tail
                     if params.oversample is not None:
                         probe_kwargs["oversample"] = params.oversample
+            called = time.monotonic()
+            waits_ms = [(called - s.submitted) * 1e3 for s in items]
+            for s, wait_ms in zip(items, waits_ms):
+                self.metrics.histogram("serving.queue_wait_ms", s.tenant).observe(wait_ms)
             try:
-                report = self.coordinator.probe_batch(
-                    self.table_name,
-                    queries,
-                    k_eff,
-                    strategy=self.strategy,
-                    filter=filters if any_filtered else None,
-                    **probe_kwargs,
-                )
+                with span("serving.batch", trace_id=next(self._batch_seq),
+                          probes=len(items), k=k_eff,
+                          queue_wait_ms=sum(waits_ms) / len(waits_ms)):
+                    report = self.coordinator.probe_batch(
+                        self.table_name,
+                        queries,
+                        k_eff,
+                        strategy=self.strategy,
+                        filter=filters if any_filtered else None,
+                        **probe_kwargs,
+                    )
             except Exception as exc:  # propagate to every waiter
                 for s in items:
                     s.fut.set_exception(exc)
