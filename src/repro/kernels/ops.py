@@ -55,6 +55,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.kernels import autotune, ref
 from repro.kernels.kmeans_assign import kmeans_assign_pallas
@@ -136,6 +137,49 @@ def exact_distances(
         q_pad, x_pad, metric=metric, tile_q=tile_q, tile_n=tile_n, interpret=interpret
     )
     return out[:q0, :n0]
+
+
+STAGE_B_MIN_ROWS = 256  # smallest candidate-row bucket of bucketed_exact_distances
+
+
+def _stage_b_buckets(q: int, n: int) -> Tuple[int, int]:
+    """(queries, rows) bucket of a (q, n) Stage-B call: queries up to a
+    multiple of the traversal's query batch, rows up to the next power of
+    two (at least ``STAGE_B_MIN_ROWS``), so a bucket wastes at most half its
+    rows."""
+    from repro.core.vamana import QUERY_BATCH  # lazy: vamana -> pq -> ops
+
+    qb = -(-max(q, 1) // QUERY_BATCH) * QUERY_BATCH
+    nb = max(STAGE_B_MIN_ROWS, 1 << (max(n, 1) - 1).bit_length())
+    return qb, nb
+
+
+@functools.partial(jax.jit, static_argnames=("metric",))
+def _bucket_distances(queries: jnp.ndarray, points: jnp.ndarray, metric: str) -> jnp.ndarray:
+    fn = ref.l2_distances if metric == "l2" else ref.ip_distances
+    return fn(queries, points)
+
+
+def bucketed_exact_distances(
+    queries: np.ndarray, points: np.ndarray, *, metric: str = "l2"
+) -> np.ndarray:
+    """(Q, D) × (N, D) host arrays → (Q, N) host distance matrix (squared L2
+    or -IP): the math of ``exact_distances(backend="ref")``, run as ONE jitted
+    program per (queries, rows) bucket instead of eager jnp per exact shape.
+
+    Both arrays are zero-padded on the host to their bucket
+    (:func:`_stage_b_buckets`) before they reach the device, and the padded
+    matrix is sliced back on the host, so a call whose bucket is warm
+    compiles nothing — no eager pad or slice runs outside the jit.  Padded
+    rows and queries never leave this function."""
+    q = np.asarray(queries, np.float32)
+    x = np.asarray(points, np.float32)
+    q0, n0 = q.shape[0], x.shape[0]
+    qb, nb = _stage_b_buckets(q0, n0)
+    q_pad = np.pad(q, ((0, qb - q0), (0, 0)))
+    x_pad = np.pad(x, ((0, nb - n0), (0, 0)))
+    out = _bucket_distances(jnp.asarray(q_pad), jnp.asarray(x_pad), metric)
+    return np.asarray(out)[:q0, :n0]
 
 
 def exact_topk(

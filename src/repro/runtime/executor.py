@@ -1407,14 +1407,12 @@ class Executor:
         if not rows_flat:
             result.rows = [[] for _ in range(q.shape[0])]
             return result
-        # the union of every query's rows is read and scored ONCE — a single
-        # batched kernel call; ownership filters the (Q, N) matrix afterwards
+        # the union of every query's rows is read and scored ONCE — one jitted
+        # program per shape bucket, so a warm bucket compiles nothing; the
+        # (Q, N) matrix comes back unpadded and ownership filters it afterwards
         with span("executor.rerank.score"):
-            cands = np.concatenate(vec_parts)
-            d = np.asarray(
-                ops.exact_distances(
-                    jnp.asarray(q), jnp.asarray(cands), metric=task.metric, backend="ref"
-                )
+            d = ops.bucketed_exact_distances(
+                q, np.concatenate(vec_parts), metric=task.metric
             )
         with span("executor.rerank.emit"):
             for qi in range(q.shape[0]):

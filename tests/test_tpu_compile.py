@@ -19,6 +19,7 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 from repro.core.vamana import _beam_search, _masked_beam_search, _robust_prune
+from repro.kernels import ops
 from repro.kernels.kmeans_assign import kmeans_assign_pallas
 from repro.kernels.masked_topk import (
     masked_exact_topk_multi_pallas,
@@ -139,6 +140,17 @@ def test_rerank_distances_and_pq_scan_compile(one_chip):
         interpret=False,
     ).compile()
     assert _compiled_kernel(dists) and _compiled_kernel(adc)
+
+
+@pytest.mark.parametrize("metric", ["l2", "ip"])
+@pytest.mark.parametrize("rows", [256, 2048])
+def test_stage_b_bucket_distances_compile(one_chip, rows, metric):
+    compiled = ops._bucket_distances.lower(
+        _sds((Q, D), jnp.float32, one_chip),
+        _sds((rows, D), jnp.float32, one_chip),
+        metric,
+    ).compile()
+    assert compiled.memory_analysis() is not None
 
 
 def test_kmeans_assign_compiles(one_chip):
