@@ -358,7 +358,11 @@ def _robust_prune(
         result = result.at[:, step].set(jnp.where(ok, pick_id, -1))
         pvec = s_vecs[row, pick]  # (B, D)
         d_star = _pair_dist(pvec[:, None, :], s_vecs, metric)  # (B, C)
-        kill = alpha * d_star <= s_dp  # removes pick itself (d_star=0)
+        # l2: d_star is 0 at the pick, so the pick removes itself.  ip: the
+        # scores are negative, so alpha > 1 prunes more rather than less, and
+        # the pick removes itself only where alpha * -|pick|^2 <= its own
+        # score (ROADMAP queue 2.8)
+        kill = alpha * d_star <= s_dp
         alive = alive & ~kill & ok[:, None]
         return alive, result
 

@@ -1,7 +1,11 @@
 """Compile the main-path programs for a described TPU v5e, no chip needed.
 
 The TPU compiler runs here against a ``v5e:2x2`` topology description at the
-paper's width (D=768), a 65,536-row shard, a 64-query batch and k=100.  It
+paper's width (D=768, ``pq_m=48``), a 65,536-row shard, a 64-query batch and
+k=100.  The programs whose shapes grow with the width (the gather-rerank
+kernel, the Stage-B bucket distances, the k-means assignment, the beam search
+with and without PQ, the robust prune) also compile at the OpenAI embedding
+width (D=1536, ``pq_m=96``); both widths keep 16-d PQ subspaces.  The compiler
 refuses what interpret mode accepts: unaligned or mislaid blocks, too much
 VMEM, an unpartitionable sharded program.  Nothing runs, so these tests say
 nothing about results or speed.
@@ -35,6 +39,9 @@ D, N, Q, K = 768, 65536, 64, 100
 R, L = 64, 100
 MAX_ITERS = int(1.3 * L) + 8
 PQ_M, PQ_K = 48, 256
+PQ_DSUB = 16  # pq_m = D / 16 in both deployments
+# D of the benchmark's deployments: cohere-768d, openai-1536d
+WIDTHS = pytest.mark.parametrize("dim", [768, 1536], ids=["D768", "D1536"])
 
 
 @pytest.fixture(scope="module")
@@ -117,11 +124,12 @@ def test_unified_masked_topk_compiles(one_chip):
     assert _compiled_kernel(compiled)
 
 
+@WIDTHS
 @pytest.mark.parametrize("pool", [256, 512])
-def test_gather_rerank_compiles(one_chip, pool):
+def test_gather_rerank_compiles(one_chip, pool, dim):
     compiled = gather_rerank_pallas.lower(
-        _sds((Q, D), jnp.float32, one_chip),
-        _sds((N, D), jnp.float32, one_chip),
+        _sds((Q, dim), jnp.float32, one_chip),
+        _sds((N, dim), jnp.float32, one_chip),
         _sds((Q, pool), jnp.int32, one_chip),
         k=K, interpret=False,
     ).compile()
@@ -142,30 +150,34 @@ def test_rerank_distances_and_pq_scan_compile(one_chip):
     assert _compiled_kernel(dists) and _compiled_kernel(adc)
 
 
+@WIDTHS
 @pytest.mark.parametrize("metric", ["l2", "ip"])
 @pytest.mark.parametrize("rows", [256, 2048])
-def test_stage_b_bucket_distances_compile(one_chip, rows, metric):
+def test_stage_b_bucket_distances_compile(one_chip, rows, metric, dim):
     compiled = ops._bucket_distances.lower(
-        _sds((Q, D), jnp.float32, one_chip),
-        _sds((rows, D), jnp.float32, one_chip),
+        _sds((Q, dim), jnp.float32, one_chip),
+        _sds((rows, dim), jnp.float32, one_chip),
         metric,
     ).compile()
     assert compiled.memory_analysis() is not None
 
 
-def test_kmeans_assign_compiles(one_chip):
+@WIDTHS
+def test_kmeans_assign_compiles(one_chip, dim):
     compiled = kmeans_assign_pallas.lower(
-        _sds((N, D), jnp.float32, one_chip),
-        _sds((128, D), jnp.float32, one_chip),
+        _sds((N, dim), jnp.float32, one_chip),
+        _sds((128, dim), jnp.float32, one_chip),
         tile_n=256, tile_k=128, interpret=False,
     ).compile()
     assert _compiled_kernel(compiled)
 
 
+@WIDTHS
 @pytest.mark.parametrize("use_pq", [False, True])
-def test_beam_search_compiles(one_chip, use_pq):
-    points = (N, PQ_M) if use_pq else (N, D)
-    queries = (Q, PQ_M, PQ_K) if use_pq else (Q, D)
+def test_beam_search_compiles(one_chip, use_pq, dim):
+    pq_m = dim // PQ_DSUB
+    points = (N, pq_m) if use_pq else (N, dim)
+    queries = (Q, pq_m, PQ_K) if use_pq else (Q, dim)
     compiled = _beam_search.lower(
         _sds(points, jnp.int32 if use_pq else jnp.float32, one_chip),
         _sds((N, R), jnp.int32, one_chip),
@@ -191,11 +203,12 @@ def test_masked_beam_search_compiles(one_chip):
     assert compiled.memory_analysis() is not None
 
 
-def test_robust_prune_compiles(one_chip):
+@WIDTHS
+def test_robust_prune_compiles(one_chip, dim):
     batch = 128
     compiled = _robust_prune.lower(
-        _sds((N, D), jnp.float32, one_chip),
-        _sds((batch, D), jnp.float32, one_chip),
+        _sds((N, dim), jnp.float32, one_chip),
+        _sds((batch, dim), jnp.float32, one_chip),
         _sds((batch, L + MAX_ITERS), jnp.int32, one_chip),
         _sds((), jnp.int32, one_chip),
         R, 1.2, "l2",
