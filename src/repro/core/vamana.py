@@ -23,6 +23,7 @@ Entry points:
 from __future__ import annotations
 
 import functools
+import threading
 from dataclasses import dataclass, field
 from typing import Optional, Tuple
 
@@ -47,6 +48,66 @@ class VamanaParams:
 # queries per traversal call of the search methods (each batch also costs
 # one gather_rerank call on the PQ paths)
 QUERY_BATCH = 64
+# a call's query slots come in multiples of two f32 sublane tiles
+SLOT_STEP = 16
+SLOT_BUCKETS = tuple(range(SLOT_STEP, QUERY_BATCH + 1, SLOT_STEP))
+
+
+def query_slots(n: int) -> int:
+    """Query slots of one traversal call carrying ``n`` queries: the smallest
+    multiple of ``SLOT_STEP`` that holds them, capped at ``QUERY_BATCH``.  A
+    padded slot does a real row's work at every step, so a call runs only
+    the slots its queries need, from the few ``SLOT_BUCKETS`` shapes."""
+    return min(QUERY_BATCH, -(-max(int(n), 1) // SLOT_STEP) * SLOT_STEP)
+
+
+def stream_slots(n: int) -> int:
+    """Query slots the traversal calls of an ``n``-query stream run: chunks of
+    ``QUERY_BATCH``, the last of them bucketed by :func:`query_slots`."""
+    full, rest = divmod(int(n), QUERY_BATCH)
+    return full * QUERY_BATCH + (query_slots(rest) if rest else 0)
+
+
+def _pad_rows(a: np.ndarray, rows: int) -> np.ndarray:
+    pad = rows - a.shape[0]
+    return np.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1)) if pad else a
+
+
+def _in_chunks(queries: np.ndarray, k: int, traverse):
+    """Run ``traverse`` over ``queries`` in chunks of ``QUERY_BATCH`` rows
+    and stack its (dists (rows, k), ids (rows, k)) answers."""
+    out_d = np.empty((queries.shape[0], k), np.float32)
+    out_i = np.empty((queries.shape[0], k), np.int64)
+    for s in range(0, queries.shape[0], QUERY_BATCH):
+        e = s + QUERY_BATCH
+        out_d[s:e], out_i[s:e] = traverse(queries[s:e])
+    return out_d, out_i
+
+
+# shapes whose buckets have compiled: process-wide, as JAX's own cache of
+# compiled programs is.  Executor threads first search their shards at once:
+# one lock a shape, so shards of one shape warm it once and shards of other
+# shapes warm theirs alongside
+_warm_lock = threading.Lock()
+_shape_locks: dict = {}
+_warm_shapes: set = set()
+
+
+def _warm_slot_buckets(shape: tuple, run) -> None:
+    """The first time the process traverses a graph of ``shape`` (every
+    static argument and array shape of the call's programs), ``run(slots)``
+    traverses zero queries at each of ``SLOT_BUCKETS``: each bucket's
+    programs compile here, and no later call of that shape compiles."""
+    if shape in _warm_shapes:
+        return
+    with _warm_lock:
+        lock = _shape_locks.setdefault(shape, threading.Lock())
+    with lock:
+        if shape in _warm_shapes:
+            return
+        for slots in SLOT_BUCKETS:
+            run(slots)
+        _warm_shapes.add(shape)
 
 
 # ---------------------------------------------------------------------------
@@ -419,22 +480,20 @@ class VamanaGraph:
         queries: np.ndarray,
         k: int,
         L: Optional[int] = None,
-        batch: int = QUERY_BATCH,
     ) -> Tuple[np.ndarray, np.ndarray]:
         """Full-precision beam search.  Returns (dists (Q,k), ids (Q,k));
         tombstoned nodes traversed but filtered (paper §7.3)."""
         L = max(L or self.params.L, k)
         queries = np.ascontiguousarray(queries, dtype=np.float32)
-        out_d = np.empty((queries.shape[0], k), np.float32)
-        out_i = np.empty((queries.shape[0], k), np.int64)
         max_iters = int(1.3 * L) + 8
-        for s in range(0, queries.shape[0], batch):
-            q = queries[s : s + batch]
-            pad = batch - q.shape[0]
-            qb = np.pad(q, ((0, pad), (0, 0))) if pad else q
+        vectors_j = jnp.asarray(self.vectors)
+        adj_j = jnp.asarray(self.adjacency)
+
+        def traverse(q):
+            qb = _pad_rows(q, query_slots(q.shape[0]))
             ids, dists, _, _ = _beam_search(
-                jnp.asarray(self.vectors),
-                jnp.asarray(self.adjacency),
+                vectors_j,
+                adj_j,
                 jnp.int32(self.n),
                 jnp.int32(self.medoid),
                 jnp.asarray(qb),
@@ -443,17 +502,25 @@ class VamanaGraph:
                 self.params.metric,
                 False,
             )
-            ids_np = np.asarray(ids)
-            dists_np = np.asarray(dists)
-            # lazy-tombstone filter
-            ts = self.tombstones[np.clip(ids_np, 0, self.vectors.shape[0] - 1)]
-            dists_np = np.where(ts | (ids_np >= self.n), np.inf, dists_np)
-            order = np.argsort(dists_np, axis=1)[:, :k]
-            d = np.take_along_axis(dists_np, order, axis=1)
-            i = np.take_along_axis(ids_np, order, axis=1)
-            out_d[s : s + q.shape[0]] = d[: q.shape[0]]
-            out_i[s : s + q.shape[0]] = i[: q.shape[0]]
-        return out_d, out_i
+            return self._filter_topk(np.asarray(ids), np.asarray(dists), k, q.shape[0])
+
+        _warm_slot_buckets(
+            ("search", self.vectors.shape, self.adjacency.shape, L, self.params.metric),
+            lambda slots: traverse(np.zeros((slots, self.dim), np.float32)),
+        )
+        return _in_chunks(queries, k, traverse)
+
+    def _filter_topk(self, ids_np, dists_np, k: int, rows: int):
+        """Lazy-tombstone filter of a traversal's pools, then each of the
+        first ``rows`` rows' ``k`` nearest: (dists, ids)."""
+        ids_np, dists_np = ids_np[:rows], dists_np[:rows]
+        ts = self.tombstones[np.clip(ids_np, 0, self.vectors.shape[0] - 1)]
+        dists_np = np.where(ts | (ids_np >= self.n), np.inf, dists_np)
+        order = np.argsort(dists_np, axis=1)[:, :k]
+        return (
+            np.take_along_axis(dists_np, order, axis=1),
+            np.take_along_axis(ids_np, order, axis=1),
+        )
 
     def search_pq(
         self,
@@ -461,7 +528,6 @@ class VamanaGraph:
         k: int,
         L: Optional[int] = None,
         rerank: bool = True,
-        batch: int = QUERY_BATCH,
     ) -> Tuple[np.ndarray, np.ndarray]:
         """Stage-A probe: PQ-approximate traversal + full-precision rerank of
         the candidate pool (paper §6)."""
@@ -469,18 +535,17 @@ class VamanaGraph:
             raise ValueError("graph has no PQ data; call attach_pq()")
         L = max(L or self.params.L, k)
         queries = np.ascontiguousarray(queries, dtype=np.float32)
-        out_d = np.empty((queries.shape[0], k), np.float32)
-        out_i = np.empty((queries.shape[0], k), np.int64)
         max_iters = int(1.3 * L) + 8
         codes_j = jnp.asarray(self.pq_codes.astype(np.int32))
-        for s in range(0, queries.shape[0], batch):
-            q = queries[s : s + batch]
-            pad = batch - q.shape[0]
-            qb = np.pad(q, ((0, pad), (0, 0))) if pad else q
+        adj_j = jnp.asarray(self.adjacency)
+
+        def traverse(q):
+            rows = q.shape[0]
+            qb = _pad_rows(q, query_slots(rows))
             luts = build_luts(self.pq, qb)  # (B, m, K)
             ids, dists, vis_ids, _vis_d = _beam_search(
                 codes_j,
-                jnp.asarray(self.adjacency),
+                adj_j,
                 jnp.int32(self.n),
                 jnp.int32(self.medoid),
                 luts,
@@ -490,48 +555,48 @@ class VamanaGraph:
                 True,
             )
             ids_np = np.asarray(ids)
-            dists_np = np.asarray(dists)
-            if rerank:
-                # DiskANN-style rerank: every *visited* node's full vector is
-                # already paged in during traversal, so the exact rerank runs
-                # over pool ∪ visited, not just the final PQ-ranked pool —
-                # this is what keeps recall high when PQ noise exceeds the
-                # within-cluster distance gaps.  Duplicates, out-of-range ids
-                # and tombstones all fold to the pid=-1 sentinel; the
-                # gather-rerank kernel (kernels/rerank.py) scores the rest
-                # on-device — no (B, C, D) host gather.
-                from repro.kernels import device_cache, ops
+            if not rerank:
+                return self._filter_topk(ids_np, np.asarray(dists), k, rows)
+            # DiskANN-style rerank: every *visited* node's full vector is
+            # already paged in during traversal, so the exact rerank runs
+            # over pool ∪ visited, not just the final PQ-ranked pool —
+            # this is what keeps recall high when PQ noise exceeds the
+            # within-cluster distance gaps.  Duplicates, out-of-range ids
+            # and tombstones all fold to the pid=-1 sentinel; the
+            # gather-rerank kernel (kernels/rerank.py) scores the rest
+            # on-device — no (B, C, D) host gather.
+            from repro.kernels import device_cache, ops
 
-                cand = np.concatenate([ids_np, np.asarray(vis_ids)], axis=1)
-                sort_idx = np.argsort(cand, axis=1, kind="stable")
-                sorted_ids = np.take_along_axis(cand, sort_idx, axis=1)
-                dup = np.concatenate(
-                    [
-                        np.zeros((cand.shape[0], 1), bool),
-                        sorted_ids[:, 1:] == sorted_ids[:, :-1],
-                    ],
-                    axis=1,
-                )
-                safe = np.clip(sorted_ids, 0, self.vectors.shape[0] - 1)
-                bad = dup | (sorted_ids >= self.n) | self.tombstones[safe]
-                pids = np.where(bad, -1, sorted_ids).astype(np.int32)
-                rd, ri = ops.gather_rerank(
-                    jnp.asarray(qb),
-                    device_cache.device_vectors(self),
-                    jnp.asarray(pids),
-                    k,
-                    metric=self.params.metric,
-                    backend="auto",
-                )
-                out_d[s : s + q.shape[0]] = np.asarray(rd)[: q.shape[0]]
-                out_i[s : s + q.shape[0]] = np.asarray(ri, np.int64)[: q.shape[0]]
-                continue
-            ts = self.tombstones[np.clip(ids_np, 0, self.vectors.shape[0] - 1)]
-            dists_np = np.where(ts | (ids_np >= self.n), np.inf, dists_np)
-            order = np.argsort(dists_np, axis=1)[:, :k]
-            out_d[s : s + q.shape[0]] = np.take_along_axis(dists_np, order, axis=1)[: q.shape[0]]
-            out_i[s : s + q.shape[0]] = np.take_along_axis(ids_np, order, axis=1)[: q.shape[0]]
-        return out_d, out_i
+            cand = np.concatenate([ids_np, np.asarray(vis_ids)], axis=1)
+            sort_idx = np.argsort(cand, axis=1, kind="stable")
+            sorted_ids = np.take_along_axis(cand, sort_idx, axis=1)
+            dup = np.concatenate(
+                [
+                    np.zeros((cand.shape[0], 1), bool),
+                    sorted_ids[:, 1:] == sorted_ids[:, :-1],
+                ],
+                axis=1,
+            )
+            safe = np.clip(sorted_ids, 0, self.vectors.shape[0] - 1)
+            bad = dup | (sorted_ids >= self.n) | self.tombstones[safe]
+            pids = np.where(bad, -1, sorted_ids).astype(np.int32)
+            rd, ri = ops.gather_rerank(
+                jnp.asarray(qb),
+                device_cache.device_vectors(self),
+                jnp.asarray(pids),
+                k,
+                metric=self.params.metric,
+                backend="auto",
+            )
+            return np.asarray(rd)[:rows], np.asarray(ri, np.int64)[:rows]
+
+        # the rerank's points are the graph's first n rows
+        _warm_slot_buckets(
+            ("search_pq", self.n, self.vectors.shape, self.pq_codes.shape,
+             self.pq.codebook.shape, self.adjacency.shape, L, k, rerank, self.params.metric),
+            lambda slots: traverse(np.zeros((slots, self.dim), np.float32)),
+        )
+        return _in_chunks(queries, k, traverse)
 
     def search_masked(
         self,
@@ -593,12 +658,12 @@ class VamanaGraph:
         else:
             vecs_j = jnp.asarray(self.vectors)
         adj_j = jnp.asarray(self.adjacency)
+        batch = min(int(batch), QUERY_BATCH)  # a call runs at most QUERY_BATCH slots
         for s in range(0, Q, batch):
             q = queries[s : s + batch]
-            pad = batch - q.shape[0]
-            qb = np.pad(q, ((0, pad), (0, 0))) if pad else q
-            ib = idx_np[s : s + batch]
-            ib = np.pad(ib, (0, pad)) if pad else ib
+            slots = query_slots(q.shape[0])
+            qb = _pad_rows(q, slots)
+            ib = _pad_rows(idx_np[s : s + batch], slots)
             if use_pq:
                 luts = build_luts(self.pq, qb)
                 res_i, _res_d, vis_i = _masked_beam_search(

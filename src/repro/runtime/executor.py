@@ -26,14 +26,14 @@ import numpy as np
 from repro.core.blobs import ShardLocationMap, decode_shard_blob, encode_shard_blob
 from repro.runtime import planner
 from repro.runtime.predicates import row_group_mask
-from repro.core.vamana import QUERY_BATCH, VamanaGraph, VamanaParams, build_vamana
+from repro.core.vamana import QUERY_BATCH, VamanaGraph, VamanaParams, build_vamana, stream_slots
 from repro.core.pq import PQCodebook, encode as pq_encode
 from repro.iceberg.puffin import _decompress  # codec shared with Puffin blobs
 from repro.kernels import device_cache, ops
 from repro.lakehouse.objectstore import ObjectStore
 from repro.lakehouse.vparquet import VParquetReader
 from repro.runtime import fragments as F
-from repro.serving.metrics import span
+from repro.serving.metrics import count, span
 
 import jax.numpy as jnp
 
@@ -889,11 +889,13 @@ class Executor:
         q = task.queries if queries is None else queries
         k_eff = min(width or task.k * task.oversample, graph.num_live)
         L = max(task.L, k_eff)
+        slots = stream_slots(len(q))
+        count("traversal.padded_slots", slots - len(q))
         if task.use_pq and graph.pq is not None:
             self._count_graph_reranks(q)
-            with span("traversal.search_pq"):
+            with span("traversal.search_pq", queries=len(q), slots=slots):
                 return graph.search_pq(q, k_eff, L=L)
-        with span("traversal.search"):
+        with span("traversal.search", queries=len(q), slots=slots):
             return graph.search(q, k_eff, L=L)
 
     def _row_candidates(

@@ -32,6 +32,8 @@ process by :func:`set_tracing`:
   ``compiles[<span name>]`` / ``compile_s[<span name>]`` (``[none]`` outside
   any span) of the registry passed to :func:`set_tracing`.
 
+:func:`count` adds to a counter of that registry, and only while tracing is on.
+
 :func:`self_ns` is a span's duration minus the union of its children's.
 
 Nothing here imports jax or the runtime at import time — the registry is
@@ -252,6 +254,14 @@ def timed(name: str, **attrs):
     if _registry is None:
         return _Stopwatch()
     return _Span(name, None, attrs)
+
+
+def count(name: str, n: float = 1) -> None:
+    """Add ``n`` to the counter ``name`` of the registry that tracing is on
+    with; nothing while tracing is off."""
+    registry = _registry
+    if registry is not None:
+        registry.counter(name).inc(n)
 
 
 def _on_compile(event: str, secs: float, **_) -> None:

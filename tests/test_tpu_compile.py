@@ -2,9 +2,10 @@
 
 The TPU compiler runs here against a ``v5e:2x2`` topology description at the
 paper's width (D=768, ``pq_m=48``), a 65,536-row shard, a 64-query batch and
-k=100.  The programs whose shapes grow with the width (the gather-rerank
-kernel, the Stage-B bucket distances, the k-means assignment, the beam search
-with and without PQ, the robust prune) also compile at the OpenAI embedding
+k=100; the beam search also at its smaller query-slot buckets (16, 32, 48).
+The programs whose shapes grow with the width (the gather-rerank kernel, the
+Stage-B bucket distances, the k-means assignment, the beam search with and
+without PQ, the robust prune) also compile at the OpenAI embedding
 width (D=1536, ``pq_m=96``); both widths keep 16-d PQ subspaces.  The compiler
 refuses what interpret mode accepts: unaligned or mislaid blocks, too much
 VMEM, an unpartitionable sharded program.  Nothing runs, so these tests say
@@ -178,6 +179,26 @@ def test_beam_search_compiles(one_chip, use_pq, dim):
     pq_m = dim // PQ_DSUB
     points = (N, pq_m) if use_pq else (N, dim)
     queries = (Q, pq_m, PQ_K) if use_pq else (Q, dim)
+    compiled = _beam_search.lower(
+        _sds(points, jnp.int32 if use_pq else jnp.float32, one_chip),
+        _sds((N, R), jnp.int32, one_chip),
+        _sds((), jnp.int32, one_chip),
+        _sds((), jnp.int32, one_chip),
+        _sds(queries, jnp.float32, one_chip),
+        L, MAX_ITERS, "l2", use_pq,
+    ).compile()
+    assert compiled.memory_analysis() is not None
+
+
+@WIDTHS
+@pytest.mark.parametrize("use_pq", [False, True])
+@pytest.mark.parametrize("slots", [16, 32, 48])
+def test_beam_search_compiles_at_fewer_slots(one_chip, slots, use_pq, dim):
+    """A traversal call runs only the query slots its queries need
+    (``vamana.query_slots``): the buckets below the full 64."""
+    pq_m = dim // PQ_DSUB
+    points = (N, pq_m) if use_pq else (N, dim)
+    queries = (slots, pq_m, PQ_K) if use_pq else (slots, dim)
     compiled = _beam_search.lower(
         _sds(points, jnp.int32 if use_pq else jnp.float32, one_chip),
         _sds((N, R), jnp.int32, one_chip),

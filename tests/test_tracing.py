@@ -173,6 +173,31 @@ def test_tracing_off_logs_nothing_and_registers_nothing(built_cluster, monkeypat
     assert metrics._on_compile not in monitoring._event_duration_secs_listeners
 
 
+def test_traversal_spans_carry_queries_and_slots_and_count_the_padding(registry, built_cluster):
+    from repro.core.vamana import query_slots
+
+    _probe(built_cluster, n=21, seed=4)
+    spans = [s for s in metrics.drain() if s["name"].startswith("traversal.search")]
+    assert spans
+    for s in spans:
+        queries, slots = s["attrs"]["queries"], s["attrs"]["slots"]
+        assert 1 <= queries <= 21
+        assert slots == query_slots(queries)
+    padded = sum(s["attrs"]["slots"] - s["attrs"]["queries"] for s in spans)
+    assert padded > 0
+    assert registry.counter_value("traversal.padded_slots") == padded
+
+
+def test_padded_slots_are_not_counted_with_tracing_off(built_cluster):
+    reg = MetricsRegistry()
+    metrics.set_tracing(reg)
+    metrics.set_tracing(None)
+    metrics.count("traversal.padded_slots", 5)
+    _probe(built_cluster, n=3, seed=5)
+    assert reg.snapshot() == {}
+    assert metrics.drain() == []
+
+
 def test_probe_report_stage_times_are_the_spans_durations(registry, built_cluster):
     rep = _probe(built_cluster, seed=3)
     spans = {s["name"]: s for s in metrics.drain()}
