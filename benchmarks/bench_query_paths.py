@@ -246,43 +246,42 @@ def main(tiny: bool = False, json_path: str = "BENCH_query_paths.json") -> None:
     }
 
     # ---- heterogeneous-filter batch: per-query mask planes ----------------
-    # Every query carries a DISTINCT predicate (8+ of them).  The legacy
-    # executor path degrades to one masked-kernel pass per predicate group;
-    # the mask-plane path answers the whole coalesced fragment with one
-    # multi-mask call per shard (per scoring flavor).  Both paths are
-    # measured in the same window (load cancels in the ratio) and must
-    # return identical hits; check_bench gates: fewer kernel dispatches
-    # than the per-group path, speedup > 1, recall vs oracle >= 0.95.
+    # Every query carries a DISTINCT predicate (8+ of them).  One-query
+    # probe() calls pay one masked-kernel pass per (query, shard); the
+    # batch answers the whole coalesced fragment with one multi-mask call
+    # per shard.  Both are measured in the same window (load cancels in the
+    # ratio) and must return identical hits; check_bench gates: fewer
+    # kernel dispatches than the one-query calls, speedup > 1, recall vs
+    # oracle >= 0.95.
     hetero_filters = [
         f"price < {5 + (63 * i) // max(len(Q) - 1, 1)}" for i in range(len(Q))
     ]  # est selectivities ~0.05..0.68 — all mask-/prefilter-planned
     assert len(set(hetero_filters)) >= 8
-    # warm both paths (masks cached, jit traced), then INTERLEAVE the
-    # grouped/plane timing rounds: a load spike hits the same rounds of
-    # both paths, so the speedup ratio check_bench hard-gates on stays
-    # clean — two back-to-back best-of windows would let one unlucky
-    # window fail the gate with no real regression (same reasoning as
-    # bench_kernels' round-robin timing).
+    # warm both (masks cached, jit traced), then INTERLEAVE the sequential/
+    # batch timing rounds: a load spike hits the same rounds of both, so
+    # the speedup ratio check_bench hard-gates on stays clean — two
+    # back-to-back best-of windows would let one unlucky window fail the
+    # gate with no real regression (same reasoning as bench_kernels'
+    # round-robin timing).
     def _hetero_probe():
         return c.coordinator.probe_batch(
             "bench", Q, 10, strategy="diskann", filter=hetero_filters
         )
 
-    def _grouped(flag):
-        for ex in c.executors:
-            ex.force_group_loop = flag
+    def _hetero_seq():
+        return [
+            c.coordinator.probe("bench", q, 10, strategy="diskann", filter=f)
+            for q, f in zip(Q, hetero_filters)
+        ]
 
     _hetero_probe()
-    _grouped(True)
-    _hetero_probe()
-    grp_s = het_s = float("inf")
-    pr_g = pr_h = None
+    _hetero_seq()
+    seq_s = het_s = float("inf")
+    singles = pr_h = None
     for _ in range(3):
-        _grouped(True)
         t0 = time.perf_counter()
-        pr_g = _hetero_probe()
-        grp_s = min(grp_s, time.perf_counter() - t0)
-        _grouped(False)
+        singles = _hetero_seq()
+        seq_s = min(seq_s, time.perf_counter() - t0)
         t0 = time.perf_counter()
         pr_h = _hetero_probe()
         het_s = min(het_s, time.perf_counter() - t0)
@@ -298,24 +297,25 @@ def main(tiny: bool = False, json_path: str = "BENCH_query_paths.json") -> None:
     ]))
     parity_h = all(
         [(h.file_path, h.row_group, h.row_offset) for h in a]
-        == [(h.file_path, h.row_group, h.row_offset) for h in b]
-        for a, b in zip(pr_h.hits, pr_g.hits)
+        == [(h.file_path, h.row_group, h.row_offset) for h in s.hits[0]]
+        for a, s in zip(pr_h.hits, singles)
     )
+    seq_dispatches = sum(s.kernel_dispatches for s in singles)
     emit(
         "table2.filtered_hetero",
         het_s / len(Q) * 1e6,
         f"B_{len(Q)}_distinct_{len(set(hetero_filters))}"
-        f"_dispatches_{pr_h.kernel_dispatches}_vs_grouped_{pr_g.kernel_dispatches}"
-        f"_speedup_{grp_s/het_s:.2f}x_recall_vs_oracle_{recall_h:.3f}"
+        f"_dispatches_{pr_h.kernel_dispatches}_vs_seq_{seq_dispatches}"
+        f"_speedup_{seq_s/het_s:.2f}x_recall_vs_oracle_{recall_h:.3f}"
         f"_parity_{'ok' if parity_h else 'BROKEN'}",
     )
     rows["table2.filtered_hetero"] = {
         "throughput_qps": len(Q) / het_s,
-        "grouped_qps": len(Q) / grp_s,
-        "speedup_vs_grouped": grp_s / het_s,
+        "seq_qps": len(Q) / seq_s,
+        "speedup_vs_seq": seq_s / het_s,
         "recall": recall_h,
         "kernel_dispatches": pr_h.kernel_dispatches,
-        "grouped_dispatches": pr_g.kernel_dispatches,
+        "seq_dispatches": seq_dispatches,
         "distinct_filters": len(set(hetero_filters)),
         "probe_fragments": pr_h.probe_fragments,
         "parity_ok": bool(parity_h),
@@ -325,15 +325,8 @@ def main(tiny: bool = False, json_path: str = "BENCH_query_paths.json") -> None:
     # Alternating tight (prefilter-band -> exact flavor) and wide (mask-band
     # -> PQ-ADC flavor) predicates put BOTH scoring flavors in every
     # coalesced fragment.  The unified kernel answers such a fragment in
-    # exactly ONE dispatch per shard; ``force_split_flavors`` re-enables the
-    # PR-4 two-dispatch-per-shard path for comparison.  Dispatch counts,
-    # recall, and parity come from full probe_batch runs; the speedup is
-    # measured at the EXECUTOR fragment level (one shard's Stage A, both
-    # modes interleaved in the same window) because a full probe wave rides
-    # the scheduler's 5 ms poll quantum, which would drown the one-dispatch
-    # delta in quantization noise.
-    from repro.core.blobs import ROUTING_BLOB_TYPE, decode_routing_blob
-    from repro.runtime import fragments as F
+    # exactly ONE dispatch per shard; check_bench gates that count and the
+    # recall vs the scan oracle.
     from repro.runtime import planner
 
     mixed_filters = [
@@ -342,21 +335,14 @@ def main(tiny: bool = False, json_path: str = "BENCH_query_paths.json") -> None:
     ]
     assert len(set(mixed_filters)) >= 8
 
-    def _split(flag):
-        for ex in c.executors:
-            ex.force_split_flavors = flag
-
     def _mixed_probe():
         return c.coordinator.probe_batch(
             "bench", Q, 10, strategy="diskann", filter=mixed_filters
         )
 
-    _mixed_probe()  # warm masks + jit (both modes share them)
-    _split(True)
-    pr_s = _mixed_probe()
-    _split(False)
-    pr_u = _mixed_probe()
+    _mixed_probe()  # warm masks + jit
     mixed_s = float("inf")
+    pr_u = None
     for _ in range(3):
         t0 = time.perf_counter()
         pr_u = _mixed_probe()
@@ -380,83 +366,19 @@ def main(tiny: bool = False, json_path: str = "BENCH_query_paths.json") -> None:
         / max(len(tm), 1)
         for hits, tm in zip(pr_u.hits, truth_m)
     ]))
-    parity_m = all(
-        [(h.file_path, h.row_group, h.row_offset, h.distance) for h in a]
-        == [(h.file_path, h.row_group, h.row_offset, h.distance) for h in b]
-        for a, b in zip(pr_u.hits, pr_s.hits)
-    )
-    # executor-level fragment timing: rebuild shard 0's coalesced fragment
-    # and run its Stage A directly in both modes, rounds interleaved
-    _meta, _snap, puffin_path, reader = c.coordinator._resolve_index("bench")
-    routing = decode_routing_blob(reader.read_first(ROUTING_BLOB_TYPE))
-    zonemap = c.coordinator._read_zonemap(reader, puffin_path)
-    blob_by_index = dict(enumerate(reader.blobs))
-    oversample = int(routing.params.get("oversample", "4"))
-    preds_m = [c.coordinator._coerce_filter(f) for f in mixed_filters]
-    plans_m = {
-        p: planner.plan_filtered(
-            p, zonemap, routing, k=10, oversample=oversample, use_pq=True
-        )[0]
-        for p in set(preds_m)
-    }
-    s0 = routing.shards[0]
-    b0 = blob_by_index[s0.blob_index]
-    frag = F.BatchProbeTaskInfo(
-        task_id="bench-mixed-frag",
-        cache_key=f"{puffin_path}#shard{s0.shard_id}",
-        shard_id=s0.shard_id,
-        puffin_path=puffin_path,
-        blob_offset=b0.offset,
-        blob_length=b0.length,
-        blob_codec=b0.compression_codec,
-        queries=Q,
-        query_index=np.arange(len(Q), dtype=np.int64),
-        k=10,
-        L=int(routing.params.get("L", "100")),
-        use_pq=True,
-        oversample=oversample,
-        filters=preds_m,
-        plan_ops=[plans_m[p].get(s0.shard_id) for p in preds_m],
-    )
-    ex0 = c.executors[0]
-    for flag in (True, False):  # warm both modes
-        ex0.force_split_flavors = flag
-        ex0.handle(frag)
-    # paired interleaved MEDIANS: the two modes differ by a fixed
-    # per-dispatch overhead (~10%) on top of shared compute, and either
-    # mode occasionally eats a multi-ms allocator/GC spike (measured:
-    # std 10x the mode gap) that would poison a mean and make a
-    # min-of-rounds ratio a race between two noise floors — the medians
-    # of the same alternating windows track the systematic gap
-    split_rounds, uni_rounds = [], []
-    for _ in range(15):
-        ex0.force_split_flavors = True
-        t0 = time.perf_counter()
-        ex0.handle(frag)
-        split_rounds.append(time.perf_counter() - t0)
-        ex0.force_split_flavors = False
-        t0 = time.perf_counter()
-        ex0.handle(frag)
-        uni_rounds.append(time.perf_counter() - t0)
-    split_s = float(np.median(split_rounds))
-    uni_s = float(np.median(uni_rounds))
     emit(
         "table2.filtered_mixed_flavor",
         mixed_s / len(Q) * 1e6,
         f"B_{len(Q)}_distinct_{len(set(mixed_filters))}"
-        f"_dispatches_{pr_u.kernel_dispatches}_vs_split_{pr_s.kernel_dispatches}"
-        f"_fragments_{pr_u.probe_fragments}_frag_speedup_{split_s/uni_s:.2f}x"
-        f"_recall_vs_oracle_{recall_m:.3f}_parity_{'ok' if parity_m else 'BROKEN'}",
+        f"_dispatches_{pr_u.kernel_dispatches}"
+        f"_fragments_{pr_u.probe_fragments}_recall_vs_oracle_{recall_m:.3f}",
     )
     rows["table2.filtered_mixed_flavor"] = {
         "throughput_qps": len(Q) / mixed_s,
         "recall": recall_m,
         "kernel_dispatches": pr_u.kernel_dispatches,
-        "split_dispatches": pr_s.kernel_dispatches,
         "probe_fragments": pr_u.probe_fragments,
-        "speedup_vs_split": split_s / uni_s,
         "distinct_filters": len(set(mixed_filters)),
-        "parity_ok": bool(parity_m),
     }
 
     # ---- low-selectivity predicate on a BIG shard: MaskedBeam vs postfilter

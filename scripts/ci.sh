@@ -49,13 +49,11 @@
 #             broken batched/sequential parity, batched throughput not
 #             above sequential, filtered recall-vs-oracle < 0.95, zone
 #             pruning not reducing fragments, the heterogeneous-filter
-#             row (table2.filtered_hetero) not beating the
-#             per-predicate-group path in its interleaved timing window
-#             or not reducing kernel dispatches, the mixed-flavor row
-#             (table2.filtered_mixed_flavor) not completing in EXACTLY
-#             one kernel dispatch per shard / not beating the
-#             two-dispatch split-flavor path in its paired
-#             executor-level window / diverging from it, throughput
+#             row (table2.filtered_hetero) not beating one-query
+#             probe() calls in its interleaved timing window, not
+#             reducing kernel dispatches or diverging from them, the
+#             mixed-flavor row (table2.filtered_mixed_flavor) not
+#             completing in EXACTLY one kernel dispatch per shard, throughput
 #             regression vs baseline on the kernel.* rows (35% noise
 #             budget; machine factor pinned by the pure-numpy anchor.*
 #             row, so even a uniform kernel regression is caught — table2

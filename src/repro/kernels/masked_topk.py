@@ -40,8 +40,8 @@ in one f32 value per cell: 0 = masked out, 1 = score full-precision,
 2 = score ADC.  Each grid step computes both score tiles and selects per
 row before the shared top-k reduction, so the whole mixed-flavor fragment
 is ONE dispatch.  (Compute per tile doubles, but at shard scale the
-dispatch/transfer overhead dominates the filtered path — the
-``table2.filtered_mixed_flavor`` bench row gates the win.)
+dispatch/transfer overhead dominates the filtered path; the
+``table2.filtered_mixed_flavor`` bench row gates one dispatch per shard.)
 
 The exact flavor also scores in reduced precision when asked: the same
 kernel body runs on **bf16** inputs (MXU bf16 rate, f32 accumulation via

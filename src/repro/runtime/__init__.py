@@ -11,8 +11,6 @@ straggler mitigation (DESIGN.md §6).
 from repro.runtime.fragments import (  # noqa: F401
     IndexBuildResult,
     IndexBuildTaskInfo,
-    ProbeResult,
-    ProbeTaskInfo,
     RefreshResult,
     RefreshTaskInfo,
     RerankResult,

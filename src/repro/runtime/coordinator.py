@@ -680,6 +680,9 @@ class Coordinator:
     ) -> ProbeReport:
         """Vector top-k query.  ``strategy``: auto | diskann | centroid | scan.
 
+        One call of :meth:`probe_batch` with the same arguments: every row
+        of ``queries`` is answered on its own, under the one ``filter``.
+
         ``scan_dtype`` (``f32`` | ``bf16`` | ``int8``) selects the scoring
         precision of planner-emitted ExactScan ops; reduced-precision scans
         always restore full-precision distances through the gather-rerank
@@ -695,45 +698,20 @@ class Coordinator:
         ``include_tail=False`` disables the fresh-tail tier: rows appended
         since the index's base snapshot are silently dropped (the pre-fix
         behavior) and surface as ``ProbeReport.unindexed_rows`` instead."""
-        queries = np.atleast_2d(np.asarray(queries, np.float32))
-        pred = self._coerce_filter(filter)
-        self.store.metrics.reset()
-        table = LakehouseTable(self.catalog, table_name)
-        if strategy == "scan":
-            # reads the snapshot's own file list — fresh by construction
-            return self._probe_scan(table, queries, k, snapshot_id, pred=pred)
-        meta, snap, puffin_path, reader = self._resolve_index(
-            table_name, snapshot_id, as_of_ms
+        return self.probe_batch(
+            table_name,
+            queries,
+            k,
+            strategy=strategy,
+            n_probe=n_probe,
+            snapshot_id=snapshot_id,
+            as_of_ms=as_of_ms,
+            use_pq=use_pq,
+            L=L,
+            filter=filter,
+            include_tail=include_tail,
+            scan_dtype=scan_dtype,
         )
-        full_tail = self._resolve_tail(snap)
-        tail = full_tail if include_tail else None
-        routing = decode_routing_blob(reader.read_first(ROUTING_BLOB_TYPE))
-        shard_blobs = reader.blobs_of_type(SHARD_BLOB_TYPE)
-        strategy = self._choose_strategy(strategy, routing, shard_blobs)
-        if strategy == "centroid":
-            report = self._probe_centroid(
-                table, reader, queries, k, n_probe, pred=pred,
-                puffin_path=puffin_path, tail=tail,
-            )
-        else:
-            report = self._probe_diskann(
-                table,
-                routing,
-                shard_blobs,
-                puffin_path,
-                queries,
-                k,
-                use_pq=use_pq,
-                L=L,
-                pred=pred,
-                zonemap=(
-                    self._read_zonemap(reader, puffin_path) if pred is not None else None
-                ),
-                tail=tail,
-                scan_dtype=scan_dtype,
-            )
-        self._apply_tail_report(report, snap, full_tail, served=tail is not None)
-        return report
 
     @staticmethod
     def _apply_tail_report(
@@ -906,15 +884,15 @@ class Coordinator:
     ) -> ProbeReport:
         """Batched vector top-k over ``queries (B, dim)``.
 
-        Semantics match ``[probe(q) for q in queries]`` exactly, but the
-        whole batch moves through the pipeline together: routing and tiered
-        placement are vectorized, the scheduler coalesces shard probes to at
-        most one fragment per shard, executors answer all of a fragment's
-        queries with batched kernels, and Stage B reads the union of the
-        batch's candidate rows once (per-query ownership keeps results
-        independent).  ``n_route`` optionally restricts each query to the
-        shards owning its ``n_route`` nearest partitions (recall dial; the
-        default probes every shard, preserving exact parity with ``probe``).
+        Each query's answer is exactly what a one-query call (``probe``)
+        returns, but the whole batch moves through the pipeline together:
+        routing and tiered placement are vectorized, the scheduler coalesces
+        shard probes to at most one fragment per shard, executors answer all
+        of a fragment's queries with batched kernels, and Stage B reads the
+        union of the batch's candidate rows once (per-query ownership keeps
+        results independent).  ``n_route`` optionally restricts each query
+        to the shards owning its ``n_route`` nearest partitions (recall dial;
+        the default probes every shard, as ``probe`` does).
 
         ``oversample`` overrides the index's configured Stage-B rerank
         multiplier for this probe (the serving tier's DropOversample
@@ -1090,46 +1068,6 @@ class Coordinator:
         report.filtered = pred is not None
         return report
 
-    def _probe_centroid(
-        self,
-        table: LakehouseTable,
-        reader: PuffinReader,
-        queries: np.ndarray,
-        k: int,
-        n_probe: int,
-        pred: Optional[Predicate] = None,
-        puffin_path: Optional[str] = None,
-        tail: Optional[FreshTail] = None,
-    ) -> ProbeReport:
-        """Coordinator-tier probe (paper Table 2 column 2): prune the file
-        list with the centroid index, then exact-rerank only those files.
-        With a predicate the masks keep only passing rows, and the zone map
-        (when the index carries one) skips row groups that cannot match.
-        Fresh-tail files (appended since the index's base snapshot — the
-        centroid index has never seen them) join every query's file list."""
-        with timed("coordinator.stage_a") as stage_a:
-            ci = CentroidIndex.from_blob(reader.read_first(CENTROID_BLOB_TYPE))
-            pruned: List[str] = []
-            for q in queries:
-                pruned.extend(ci.probe_topk(q, n_probe))
-            if tail is not None:
-                pruned.extend(e.file_path for e in tail.entries)
-            pruned = sorted(set(pruned))
-        with timed("coordinator.stage_b") as stage_b:
-            zonemap = self._read_zonemap(reader, puffin_path) if pred is not None else None
-            masks, rg_pruned = self._filtered_masks(table, pruned, pred, zonemap)
-            reranked = self._rerank(masks, queries, ci.metric)
-        report = self._merge(reranked, queries.shape[0], k)
-        report.strategy = "centroid"
-        report.files_scanned = len(pruned)
-        report.plan = self._tail_only_plan(tail, k, queries.shape[0])
-        report.stage_a_seconds = stage_a.seconds
-        report.stage_b_seconds = stage_b.seconds
-        report.bytes_read = self.store.metrics.bytes_read
-        report.filtered = pred is not None
-        report.row_groups_pruned = rg_pruned
-        return report
-
     def _probe_centroid_batch(
         self,
         table: LakehouseTable,
@@ -1172,153 +1110,6 @@ class Coordinator:
         report.bytes_read = self.store.metrics.bytes_read
         report.filtered = pred is not None
         report.row_groups_pruned = rg_pruned
-        return report
-
-    def _probe_diskann(
-        self,
-        table: LakehouseTable,
-        routing: RoutingTable,
-        shard_blobs,
-        puffin_path: str,
-        queries: np.ndarray,
-        k: int,
-        *,
-        use_pq: Optional[bool] = None,
-        L: Optional[int] = None,
-        pred: Optional[Predicate] = None,
-        zonemap: Optional[AttrZoneMap] = None,
-        tail: Optional[FreshTail] = None,
-        scan_dtype: str = "f32",
-    ) -> ProbeReport:
-        """Three-stage distributed probe (paper §6, Figure 3).  With a
-        predicate, the zone map first prunes shards whose member row groups
-        cannot match, then every surviving shard searches under its
-        selectivity-adaptive plan.  A fresh tail adds one ExactScan fragment
-        per unindexed row group to the same Stage-A wave; its exact hits
-        merge with the graph candidates under the shared sentinel contract."""
-        oversample = int(routing.params.get("oversample", "4"))
-        if use_pq is None:
-            use_pq = int(routing.params.get("pq_m", "0")) > 0
-        L_eff = L or int(routing.params.get("L", "100"))
-        ops: Dict[int, PlanOp] = {}
-        pruned: List[int] = []
-        est_frac = 1.0
-        plan: Optional[ProbePlan] = None
-        if pred is not None:
-            ops, pruned, est_frac = planner.plan_filtered(
-                pred, zonemap, routing, k=k, oversample=oversample,
-                use_pq=use_pq, scan_dtype=scan_dtype,
-            )
-        tail_list = tail.row_group_list() if tail is not None else []
-        tail_ops: Dict[int, PlanOp] = (
-            planner.plan_tail(
-                [cnt for _, _, cnt in tail_list],
-                k=k,
-                oversample=oversample,
-                est_frac=est_frac,
-            )
-            if tail_list
-            else {}
-        )
-        if pred is not None or tail_ops:
-            plan_row = dict(ops)
-            plan_row.update({sid: planner.Skip() for sid in pruned})
-            plan_row.update(tail_ops)
-            plan = ProbePlan(
-                k=k,
-                oversample=oversample,
-                use_pq=use_pq,
-                ops=[plan_row],
-                est_selectivity=est_frac,
-                pruned_shards=tuple(pruned),
-            )
-        # ---- Stage A: parallel shard beam search -------------------------
-        with timed("coordinator.stage_a") as stage_a:
-            blob_by_index = {i: b for i, b in enumerate(PuffinReader(
-                self.store.stat(puffin_path).size, self.store.range_reader(puffin_path)
-            ).blobs)}
-            tasks = []
-            for s in routing.shards:
-                if pred is not None and s.shard_id not in ops:
-                    continue  # zone-pruned
-                b = blob_by_index[s.blob_index]
-                tasks.append(
-                    F.ProbeTaskInfo(
-                        task_id=f"probe-{s.shard_id}",
-                        cache_key=f"{puffin_path}#shard{s.shard_id}",
-                        shard_id=s.shard_id,
-                        puffin_path=puffin_path,
-                        blob_offset=b.offset,
-                        blob_length=b.length,
-                        blob_codec=b.compression_codec,
-                        queries=queries,
-                        k=k,
-                        L=L_eff,
-                        use_pq=use_pq,
-                        oversample=oversample,
-                        predicate=pred,
-                        plan_op=ops.get(s.shard_id),
-                    )
-                )
-            Q = queries.shape[0]
-            tail_tasks = self._tail_tasks(
-                tail_list,
-                tail_ops,
-                queries,
-                np.arange(Q, dtype=np.int64),
-                k=k,
-                oversample=oversample,
-                metric=routing.metric,
-                filters=[pred] * Q if pred is not None else None,
-            )
-            results = self.scheduler.run_wave(tasks + tail_tasks)
-            probe_results: List[F.ProbeResult] = results[: len(tasks)]
-            tail_results: List[F.BatchProbeResult] = results[len(tasks):]
-        # ---- merge + Stage B: exact rerank on row-group masks ---------------
-        with timed("coordinator.stage_b") as stage_b:
-            with span("coordinator.merge"):
-                keep = k * oversample
-                merged: List[List[F.ProbeCandidate]] = []
-                for qi in range(Q):
-                    cands: List[F.ProbeCandidate] = []
-                    for r in probe_results:
-                        cands.extend(r.candidates[qi])
-                    for r in tail_results:
-                        cands.extend(r.candidates.get(qi, []))
-                    cands.sort(key=lambda c: c.approx_distance)
-                    merged.append(cands[:keep])
-                masks: Dict[str, Dict[int, set]] = {}
-                for qi in range(Q):
-                    for c in merged[qi]:
-                        masks.setdefault(c.file_path, {}).setdefault(c.row_group, set()).add(
-                            c.row_offset
-                        )
-                masks_l = {
-                    fp: {rg: sorted(rows) for rg, rows in groups.items()}
-                    for fp, groups in masks.items()
-                }
-            reranked = self._rerank(masks_l, queries, routing.metric)
-        report = self._merge(reranked, Q, k)
-        report.strategy = "diskann"
-        report.served_by = [
-            f"probe:{r.shard_id}@{r.executor_id}" for r in results
-        ] + report.served_by
-        report.files_scanned = len(masks_l)
-        report.stage_a_seconds = stage_a.seconds
-        report.stage_b_seconds = stage_b.seconds
-        report.shards_probed = len(tasks)
-        report.cache_hits = sum(1 for r in probe_results if r.cache_hit)
-        report.kernel_dispatches = sum(r.kernel_dispatches for r in results)
-        report.masked_beam_rows = sum(r.masked_beam_rows for r in results)
-        report.masked_beam_fallbacks = sum(r.masked_beam_fallbacks for r in results)
-        report.bytes_read = self.store.metrics.bytes_read
-        if pred is not None:
-            report.filtered = True
-            report.filter_plan = self._plan_summary(ops, pruned)
-            report.shards_pruned = len(pruned)
-            report.fragments_pruned = len(pruned)  # one fragment per shard here
-            report.est_selectivity = est_frac
-        report.plan = plan
         return report
 
     @staticmethod
@@ -1570,15 +1361,23 @@ class Coordinator:
             # fresh-tail fragments: every query scans every tail row group (tail
             # rows are outside the routing table, so n_route cannot skip them)
             tail_list = tail.row_group_list() if tail is not None else []
+            tail_counts = [cnt for _, _, cnt in tail_list]
             tail_ops: Dict[int, PlanOp] = (
-                planner.plan_tail(
-                    [cnt for _, _, cnt in tail_list], k=k, oversample=oversample
-                )
+                planner.plan_tail(tail_counts, k=k, oversample=oversample)
                 if tail_list
                 else {}
             )
             for qi in range(B):
-                ops_grid[qi].update(tail_ops)
+                # a filtered query's tail ops carry its predicate's estimate
+                pred = preds[qi] if preds else None
+                ops_grid[qi].update(
+                    planner.plan_tail(
+                        tail_counts, k=k, oversample=oversample,
+                        est_frac=plans[pred][2],
+                    )
+                    if tail_list and pred in plans
+                    else tail_ops
+                )
             tail_tasks = self._tail_tasks(
                 tail_list,
                 tail_ops,

@@ -124,16 +124,6 @@ class Executor:
         self._mask_cache: "OrderedDict[tuple, np.ndarray]" = OrderedDict()
         self._mask_cache_capacity = 64
         self._lock = threading.Lock()
-        # debug/bench escape hatches: route heterogeneous-filter fragments
-        # through the legacy one-kernel-call-per-predicate-group loop
-        # instead of the single mask-plane call (parity tests and the
-        # table2.filtered_hetero bench compare the two paths), and/or keep
-        # mixed exact+PQ fragments on separate per-flavor dispatches
-        # instead of the fused unified kernel (the
-        # table2.filtered_mixed_flavor bench compares one vs two dispatches
-        # per shard).  Both paths interpret the SAME planner-resolved ops.
-        self.force_group_loop = False
-        self.force_split_flavors = False
         # failure injection
         self.dead = False
         self._fail_budget = 0
@@ -146,7 +136,7 @@ class Executor:
         self.cache_misses = 0
         # masked top-k kernel calls issued (single- and multi-mask flavors).
         # The executor-wide total is lock-guarded; the per-TASK counts in
-        # Probe/BatchProbeResult come from a thread-local tally (each task
+        # BatchProbeResult come from a thread-local tally (each task
         # attempt runs on its own scheduler thread), so concurrent probes
         # on one executor cannot misattribute each other's dispatches.
         self.masked_kernel_dispatches = 0
@@ -326,10 +316,7 @@ class Executor:
     def _resolve_op(self, task, op, live_mask: np.ndarray, has_pq: bool):
         """Refine a planner op with the measured match count.  ALL
         selectivity thresholds and flavor classification live in
-        runtime/planner.py — the executor only interprets the resolved op,
-        and both the mask-plane path and the ``force_group_loop`` baseline
-        resolve through this one call, so the two can never drift apart
-        (the bit-for-bit parity the tests and the bench gates assert)."""
+        runtime/planner.py — the executor only interprets the resolved op."""
         if op is None:
             op = planner.default_filtered_op(task.k, task.oversample, task.use_pq)
         return planner.resolve(
@@ -483,9 +470,8 @@ class Executor:
         kernel call scores each row's pool ids against the cached device
         vectors (kernels/rerank.py — the (Q, P, D) host gather and einsum
         this used to do in NumPy never materializes).  Sentinel slots
-        (pid < 0) stay (+inf, -1); rows are independent, so the math is
-        identical whether the pool came from a per-group call or one
-        multi-mask call over the whole fragment."""
+        (pid < 0) stay (+inf, -1); rows are independent, so a row's math
+        does not depend on which other rows share the call."""
         self._count_rerank()
         d, ids = ops.gather_rerank(
             jnp.asarray(np.ascontiguousarray(q, np.float32)),
@@ -551,7 +537,7 @@ class Executor:
         """Heterogeneous-predicate PQScan: ONE multi-mask ADC kernel call
         (dedup'd plane) scores every query's passing codes at the shared
         ``pool`` size, then the shared exact rerank.  One pool suffices for
-        bit-for-bit parity with the per-group path: planner.resolve pins
+        bit-for-bit parity with a one-query probe: planner.resolve pins
         the PQScan pool to the same constant for every PQ-flavor query of a
         fragment (see its docstring)."""
         from repro.core.pq import build_luts
@@ -623,155 +609,6 @@ class Executor:
             out_i[flavor] = ri
         return out_d, out_i
 
-    def _filtered_search(
-        self, task, graph, locmap, queries: np.ndarray, pred, op
-    ) -> Tuple[np.ndarray, np.ndarray]:
-        """Stage-A search under an attribute predicate: interpret the
-        planner's per-shard plan ``op`` for a group of queries sharing one
-        predicate.
-
-        The op is resolved against the measured match count
-        (planner.resolve — the only place flavor thresholds live), then
-        executed: ExactScan and PQScan ride the mask-aware kernels
-        (kernels/masked_topk.py — the predicate/tombstone bitmask goes into
-        the kernel as a tile input, masked-out rows score +inf before the
-        in-kernel top-k); PostfilterBeam over-fetches the ordinary beam to
-        the planner-sized pool and filters after, falling back to the
-        kernel-backed exact masked scan whenever the beam cannot surface
-        enough passing candidates — a filtered probe never silently returns
-        fewer candidates than the shard actually holds."""
-        shard_key = f"{task.cache_key or task.puffin_path}@{task.blob_offset}"
-        mask = self._predicate_mask(locmap, graph.n, pred, shard_key)
-        live_mask = mask & ~graph.tombstones[: graph.n]
-        final = self._resolve_op(task, op, live_mask, graph.pq is not None)
-        Qn = queries.shape[0]
-        if isinstance(final, planner.Skip):
-            return (
-                np.full((Qn, 1), np.inf, np.float32),
-                np.full((Qn, 1), -1, np.int64),
-            )
-        if isinstance(final, planner.PQScan):
-            return self._masked_pq_stage(
-                graph, queries, live_mask, final.pool, final.k
-            )
-        if isinstance(final, planner.ExactScan):
-            return self._exact_masked(
-                graph, queries, live_mask, final.k,
-                dtype=getattr(final, "dtype", "f32"),
-            )
-        if isinstance(final, planner.MaskedBeam):
-            return self._masked_beam(task, graph, queries, live_mask, final)
-        return self._postfilter_beam(task, graph, queries, live_mask, final)
-
-    def _postfilter_beam_core(
-        self, task, graph, queries: np.ndarray, mask_plane: np.ndarray, pool: int
-    ) -> Tuple[np.ndarray, np.ndarray]:
-        """The ONE copy of the PostfilterBeam machinery, shared by the
-        per-group interpreter (shared mask, broadcast) and the pooled
-        mask-plane path (per-row masks): over-fetch the ordinary beam to
-        the planner-sized pool, drop each row's candidates failing ITS
-        mask, and return the full post-filtered pool sorted ascending per
-        row (failures pushed to the (+inf, -1) tail).  Callers slice their
-        per-row output widths and apply their fallback policy."""
-        p = min(int(pool), graph.num_live)
-        L = max(task.L, p)
-        if task.use_pq and graph.pq is not None:
-            dists, ids = graph.search_pq(queries, p, L=L)
-        else:
-            dists, ids = graph.search(queries, p, L=L)
-        safe = np.clip(ids, 0, graph.n - 1)
-        passing = (
-            np.take_along_axis(mask_plane, safe, axis=1)
-            & (ids >= 0)
-            & np.isfinite(dists)
-        )
-        dists = np.where(passing, dists, np.inf)
-        ids = np.where(passing, ids, -1)
-        order = np.argsort(dists, axis=1)
-        return (
-            np.take_along_axis(dists, order, axis=1),
-            np.take_along_axis(ids, order, axis=1),
-        )
-
-    def _postfilter_beam(
-        self, task, graph, queries: np.ndarray, live_mask: np.ndarray, op
-    ) -> Tuple[np.ndarray, np.ndarray]:
-        """PostfilterBeam interpretation for a group sharing one mask:
-        most rows pass, so the over-fetched beam surfaces enough; queries
-        it under-delivered fall back to the exact masked scan."""
-        plane = np.broadcast_to(live_mask, (queries.shape[0], live_mask.shape[0]))
-        dists, ids = self._postfilter_beam_core(task, graph, queries, plane, op.pool)
-        dists = dists[:, : op.k]
-        ids = ids[:, : op.k]
-        short = np.isinf(dists).any(axis=1)
-        if short.any():
-            # beam under-delivered for some queries — kernel-backed exact
-            # masked scan returns exactly op.k columns, so rows align
-            rows = np.flatnonzero(short)
-            ed, ei = self._exact_masked(graph, queries[rows], live_mask, op.k)
-            dists[rows] = ed
-            ids[rows] = ei
-        return dists, ids
-
-    def _masked_beam_core(
-        self, task, graph, queries: np.ndarray, unique_masks, row_index, width: int, k: int
-    ) -> Tuple[np.ndarray, np.ndarray]:
-        """The ONE copy of the MaskedBeam machinery, shared by the
-        per-group interpreter and the pooled mask-plane path: the
-        predicate-aware traversal (``VamanaGraph.search_masked`` — masked
-        nodes expand for connectivity, only mask-passing nodes are
-        admitted) at the planner-widened admitted-candidate target, the
-        mask shipped as the dedup'd unique rows + row index.  The widened
-        ``width`` sizes only the ADMIT target — the beam depth stays at
-        ``max(task.L, k)``, because admitted candidates come from every
-        neighbor the traversal evaluates, not just the final pool.  This
-        is the structural edge over PostfilterBeam, whose pool must deepen
-        by 1/frac to surface enough passing rows.  This is a beam pass,
-        not a masked-kernel dispatch — like Beam/PostfilterBeam passes it
-        does not count toward ``kernel_dispatches`` (its fused fallback
-        does)."""
-        w = max(1, min(int(width), graph.num_live))
-        L = max(task.L, min(int(k), graph.num_live))
-        use_pq = task.use_pq and graph.pq is not None
-        if use_pq:
-            self._count_graph_reranks(queries)
-        return graph.search_masked(
-            queries,
-            w,
-            np.stack(unique_masks),
-            row_index,
-            L=L,
-            use_pq=use_pq,
-        )
-
-    def _masked_beam(
-        self, task, graph, queries: np.ndarray, live_mask: np.ndarray, op
-    ) -> Tuple[np.ndarray, np.ndarray]:
-        """MaskedBeam interpretation for a group sharing one mask: the
-        widened predicate-aware traversal delivers ``op.k`` admitted
-        candidates per row; rows it under-delivers fall back to the exact
-        masked scan — a filtered probe never silently returns fewer
-        candidates than the shard actually holds."""
-        dists, ids = self._masked_beam_core(
-            task,
-            graph,
-            queries,
-            [live_mask],
-            np.zeros(queries.shape[0], np.int64),
-            op.width,
-            op.k,
-        )
-        dists = dists[:, : op.k]
-        ids = ids[:, : op.k]
-        short = np.isinf(dists).any(axis=1)
-        self._count_mbeam(queries.shape[0], int(short.sum()))
-        if short.any():
-            rows = np.flatnonzero(short)
-            ed, ei = self._exact_masked(graph, queries[rows], live_mask, op.k)
-            dists[rows] = ed
-            ids[rows] = ei
-        return dists, ids
-
     # -- dispatch ------------------------------------------------------------
     def handle(self, task) -> object:
         """Run one fragment, as one ``executor.task`` span."""
@@ -791,8 +628,6 @@ class Executor:
             result = self._scan_partition(task)
         elif isinstance(task, F.IndexBuildTaskInfo):
             result = self._build_shard(task)
-        elif isinstance(task, F.ProbeTaskInfo):
-            result = self._probe_shard(task)
         elif isinstance(task, F.BatchProbeTaskInfo):
             result = self._probe_shard_batch(task)
         elif isinstance(task, F.TailScanTaskInfo):
@@ -918,30 +753,6 @@ class Executor:
             )
         return cands
 
-    def _probe_shard(self, task: F.ProbeTaskInfo) -> F.ProbeResult:
-        graph, locmap, hit = self._load_shard(
-            task.puffin_path, task.blob_offset, task.blob_length, task.blob_codec, task.cache_key
-        )
-        self._reset_task_tallies()
-        if task.predicate is not None:
-            dists, ids = self._filtered_search(
-                task, graph, locmap, task.queries, task.predicate, task.plan_op
-            )
-        else:
-            dists, ids = self._shard_search(task, graph)
-        mb_rows, mb_fb = self._task_mbeam()
-        result = F.ProbeResult(
-            shard_id=task.shard_id, executor_id=self.executor_id, cache_hit=hit,
-            kernel_dispatches=self._task_dispatches(),
-            masked_beam_rows=mb_rows, masked_beam_fallbacks=mb_fb,
-        )
-        with span("executor.candidates"):
-            for qi in range(task.queries.shape[0]):
-                result.candidates.append(
-                    self._row_candidates(graph, locmap, dists[qi], ids[qi], task.shard_id)
-                )
-        return result
-
     def _tail_scan(self, task: F.TailScanTaskInfo) -> F.BatchProbeResult:
         """Fresh-tail tier Stage A: score one appended-but-unindexed row
         group for every query routed to it with ONE masked exact kernel
@@ -1027,9 +838,7 @@ class Executor:
         row of a dedup'd mask plane assembled from the per-predicate
         ``_mask_cache`` bitmasks (tombstones AND-ed in); unfiltered queries
         ride a shared beam pass, or a size-capped all-ones kernel row on
-        small shards, per their planner op.  The legacy per-predicate-group
-        loop survives only behind ``force_group_loop`` for parity/bench
-        comparison."""
+        small shards, per their planner op."""
         graph, locmap, hit = self._load_shard(
             task.puffin_path, task.blob_offset, task.blob_length, task.blob_codec, task.cache_key
         )
@@ -1048,52 +857,10 @@ class Executor:
                         graph, locmap, dists[bi], ids[bi], task.shard_id
                     )
             return result
-        if self.force_group_loop:
-            self._probe_groups(task, graph, locmap, result, qidx, range(len(qidx)))
-        else:
-            self._probe_mask_plane(task, graph, locmap, result, qidx)
+        self._probe_mask_plane(task, graph, locmap, result, qidx)
         result.kernel_dispatches = self._task_dispatches()
         result.masked_beam_rows, result.masked_beam_fallbacks = self._task_mbeam()
         return result
-
-    def _probe_groups(
-        self, task, graph, locmap, result, qidx: np.ndarray, rows
-    ) -> None:
-        """Legacy per-predicate-group Stage A: one batched pass per distinct
-        (predicate, plan op) among ``rows`` — N distinct predicates degrade
-        to N sequential kernel/beam passes.  Kept ONLY behind
-        ``force_group_loop`` as the parity/bench comparison baseline; it
-        interprets the same planner-resolved ops as the mask-plane path, so
-        the two paths answer bit-identically."""
-        groups: Dict[tuple, List[int]] = {}
-        for bi in rows:
-            op = task.plan_ops[bi] if task.plan_ops else None
-            groups.setdefault((task.filters[bi], op), []).append(bi)
-        for (pred, op), members in groups.items():
-            queries = task.queries[members]
-            if pred is None:
-                if isinstance(op, planner.ExactScan):
-                    # all-ones row on a small shard: the same size-capped
-                    # exact scan the mask-plane path ships
-                    live = ~graph.tombstones[: graph.n]
-                    k_out = max(1, min(op.k, graph.n))
-                    dists, ids = self._exact_masked(
-                        graph, queries, live, k_out,
-                        dtype=getattr(op, "dtype", "f32"),
-                    )
-                else:
-                    w = op.width if isinstance(op, planner.Beam) else 0
-                    dists, ids = self._shard_search(
-                        task, graph, queries, width=w or None
-                    )
-            else:
-                dists, ids = self._filtered_search(
-                    task, graph, locmap, queries, pred, op
-                )
-            for j, bi in enumerate(members):
-                result.candidates[int(qidx[bi])] = self._row_candidates(
-                    graph, locmap, dists[j], ids[j], task.shard_id
-                )
 
     def _probe_mask_plane(
         self, task, graph, locmap, result, qidx: np.ndarray
@@ -1200,7 +967,7 @@ class Executor:
             exact_masks = [exact_masks[p] for p in keep]
             exact_keys = [exact_keys[p] for p in keep]
 
-        if exact_rows and pq_rows and not self.force_split_flavors:
+        if exact_rows and pq_rows:
             # mixed flavors: ONE unified dispatch for the whole fragment
             rows = exact_rows + pq_rows
             unique, idx = self._dedup_rows(
@@ -1283,22 +1050,36 @@ class Executor:
         ONE fused masked-kernel fallback alongside any short postfilter
         rows.  Per-query results are identical to interpreting each row
         alone: traversal rows are independent and the fallback math is
-        per-row."""
+        per-row.
+
+        The traversal is ``VamanaGraph.search_masked``: masked nodes expand
+        for connectivity, only mask-passing nodes are admitted.  The
+        planner-widened ``width`` sizes only the ADMIT target — the beam
+        depth stays at ``max(task.L, k)``, because admitted candidates come
+        from every neighbor the traversal evaluates, not just the final
+        pool.  This is the structural edge over PostfilterBeam, whose pool
+        must deepen by 1/frac to surface enough passing rows.  A traversal
+        is a beam pass, not a masked-kernel dispatch: it does not count
+        toward ``kernel_dispatches`` (its fused fallback does)."""
         short_rows: List[int] = []
         total = 0
+        use_pq = task.use_pq and graph.pq is not None
         for width, rows in sorted(rows_by_width.items()):
             unique, idx = self._dedup_rows(
                 [masks_by_row[bi] for bi in rows],
                 [task.filters[bi] for bi in rows],
             )
-            dists, ids = self._masked_beam_core(
-                task,
-                graph,
-                task.queries[rows],
-                unique,
+            queries = task.queries[rows]
+            k = max(ks_by_row[bi] for bi in rows)
+            if use_pq:
+                self._count_graph_reranks(queries)
+            dists, ids = graph.search_masked(
+                queries,
+                max(1, min(int(width), graph.num_live)),
+                np.stack(unique),
                 idx,
-                width,
-                max(ks_by_row[bi] for bi in rows),
+                L=max(task.L, min(int(k), graph.num_live)),
+                use_pq=use_pq,
             )
             total += len(rows)
             for j, bi in enumerate(rows):
@@ -1355,21 +1136,37 @@ class Executor:
         masks_by_row: Dict[int, np.ndarray],
         ks_by_row: Dict[int, int],
     ) -> List[int]:
-        """PostfilterBeam rows of a fragment: one over-fetched beam pass
-        per distinct planner pool (NOT per distinct predicate — usually a
-        single pass) through the shared ``_postfilter_beam_core``, each row
-        post-filtered under its own mask and sliced to ITS planner-resolved
-        k.  Returns the under-delivered rows so they join the fragment's
-        ONE fused masked-kernel fallback call (shared with short
-        masked-beam rows) instead of per-predicate fallbacks.  Per-query
-        results are identical to interpreting each row alone: beam rows are
-        independent and the fallback math is per-row."""
+        """PostfilterBeam rows of a fragment: one ordinary beam pass per
+        distinct planner pool (NOT per distinct predicate — usually a
+        single pass), over-fetched to that pool; each row drops the
+        candidates failing ITS mask (failures sorted to the (+inf, -1)
+        tail) and is sliced to ITS planner-resolved k.  Returns the
+        under-delivered rows so they join the fragment's ONE fused
+        masked-kernel fallback call (shared with short masked-beam rows)
+        instead of per-predicate fallbacks.  Per-query results are
+        identical to interpreting each row alone: beam rows are independent
+        and the fallback math is per-row."""
         short_rows: List[int] = []
         for pool, rows in sorted(rows_by_pool.items()):
+            queries = task.queries[rows]
             plane = np.stack([masks_by_row[bi] for bi in rows])
-            dists, ids = self._postfilter_beam_core(
-                task, graph, task.queries[rows], plane, pool
+            p = min(int(pool), graph.num_live)
+            L = max(task.L, p)
+            if task.use_pq and graph.pq is not None:
+                dists, ids = graph.search_pq(queries, p, L=L)
+            else:
+                dists, ids = graph.search(queries, p, L=L)
+            safe = np.clip(ids, 0, graph.n - 1)
+            passing = (
+                np.take_along_axis(plane, safe, axis=1)
+                & (ids >= 0)
+                & np.isfinite(dists)
             )
+            dists = np.where(passing, dists, np.inf)
+            ids = np.where(passing, ids, -1)
+            order = np.argsort(dists, axis=1)
+            dists = np.take_along_axis(dists, order, axis=1)
+            ids = np.take_along_axis(ids, order, axis=1)
             for j, bi in enumerate(rows):
                 kj = ks_by_row[bi]
                 dj, ij = dists[j, :kj], ids[j, :kj]

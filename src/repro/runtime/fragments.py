@@ -87,25 +87,6 @@ class ScanPartitionResult:
 
 
 @dataclass
-class ProbeTaskInfo(TaskBase):
-    shard_id: int = 0
-    puffin_path: str = ""
-    blob_offset: int = 0
-    blob_length: int = 0
-    blob_codec: Optional[str] = None
-    queries: Optional[np.ndarray] = None  # (Q, D)
-    k: int = 10
-    L: int = 100
-    use_pq: bool = True
-    oversample: int = 4
-    # filtered search: predicate tree applied to every query of this task,
-    # with the planner's per-shard plan op (runtime/planner.py IR; None
-    # falls back to planner.default_filtered_op — the mid-band mask plan)
-    predicate: Optional[object] = None
-    plan_op: Optional[object] = None
-
-
-@dataclass
 class ProbeCandidate:
     file_path: str
     row_group: int
@@ -113,23 +94,6 @@ class ProbeCandidate:
     approx_distance: float
     vec_id: int
     shard_id: int
-
-
-@dataclass
-class ProbeResult:
-    shard_id: int
-    executor_id: str
-    # per query: list of candidates
-    candidates: List[List[ProbeCandidate]] = field(default_factory=list)
-    cache_hit: bool = False
-    # masked top-k kernel calls this task issued (observability for the
-    # heterogeneous-filter coalescing win; 0 on pure beam paths)
-    kernel_dispatches: int = 0
-    # MaskedBeam accounting: rows answered by the predicate-aware
-    # traversal, and how many of those under-delivered and were
-    # re-answered by the fused exact-masked fallback
-    masked_beam_rows: int = 0
-    masked_beam_fallbacks: int = 0
 
 
 @dataclass
@@ -210,10 +174,9 @@ class BatchProbeResult:
     # original batch position -> candidates for that query
     candidates: Dict[int, List[ProbeCandidate]] = field(default_factory=dict)
     cache_hit: bool = False
-    # masked top-k kernel calls this fragment cost: 1 per scoring flavor on
-    # the mask-plane path, vs one per distinct predicate on the legacy
-    # group loop — the coordinator sums these into
-    # ``ProbeReport.kernel_dispatches`` and the bench gates on the drop
+    # masked top-k kernel calls this fragment cost, however many distinct
+    # predicates it carries — the coordinator sums these into
+    # ``ProbeReport.kernel_dispatches``
     kernel_dispatches: int = 0
     # MaskedBeam accounting (summed into the matching ProbeReport fields):
     # rows answered by the predicate-aware traversal, and how many of
@@ -287,7 +250,7 @@ class RerankTaskInfo(TaskBase):
     # ``file_owners[fp]`` grants every row of ``fp`` to a query subset
     # (centroid routing); ``row_owners[fp][rg][off]`` grants a single row
     # (per-query DiskANN candidates).  Both None => every query owns every
-    # row (single-query probes and full scans — the pre-batching semantics).
+    # row (full scans).
     file_owners: Optional[Dict[str, Set[int]]] = None
     row_owners: Optional[Dict[str, Dict[int, Dict[int, Set[int]]]]] = None
 

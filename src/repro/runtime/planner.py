@@ -6,8 +6,8 @@ selectivity (``MASK_MAX_FRAC`` and friends lived in coordinator.py), the
 executor re-derived per-query kernel flavors from measured match counts
 (``_plan_flavor`` / ``_pq_pool``), and the kernels imposed their own
 dispatch granularity.  Any drift between those layers silently broke the
-bit-for-bit parity the multi-mask tests and the ``table2.filtered_hetero``
-bench gate assert.  This module turns the control flow into data:
+bit-for-bit parity between a batch and its one-query probes that the
+multi-mask tests assert.  This module turns the control flow into data:
 
 - **Plan ops** — :class:`ExactScan`, :class:`PQScan`, :class:`Beam`,
   :class:`PostfilterBeam`, :class:`MaskedBeam`, :class:`Skip` — are frozen,
@@ -25,9 +25,8 @@ bench gate assert.  This module turns the control flow into data:
   replayable artifact that rides :class:`~repro.runtime.coordinator.ProbeReport`.
 
 Every selectivity threshold and flavor-classification rule in the probe
-path lives HERE and nowhere else; both the mask-plane interpreter and the
-retained ``force_group_loop`` baseline call the same :func:`resolve`, so
-the two paths cannot drift apart.
+path lives HERE and nowhere else; the executor's one interpreter (the mask
+plane) calls :func:`resolve` for every filtered query row.
 """
 
 from __future__ import annotations
@@ -393,9 +392,8 @@ def resolve(
 ) -> PlanOp:
     """Refine a coordinator op with the measured predicate match count.
 
-    This is the per-query flavor classification both executor paths (the
-    mask-plane interpreter AND the ``force_group_loop`` baseline) share, so
-    they can never drift apart:
+    This is the executor's only per-query flavor classification (the
+    mask-plane interpreter calls it for every filtered row):
 
     - zero matches  -> :class:`Skip`;
     - a small passing set (<= max(SMALL_MATCH_FACTOR·k_eff,

@@ -265,6 +265,35 @@ def test_shard_cache_filtered_hit_parity(cache_cluster):
     assert other.shard_cache_hits == 0
 
 
+def test_single_probe_consults_shard_cache(cache_cluster):
+    """``probe()`` is a one-query ``probe_batch``, so an attached shard
+    cache serves its repeats too: the repeat dispatches no Stage-A
+    fragment, reports ``cache == "shard"`` and returns the cold probe's
+    hits bit for bit, distances included."""
+    c, t, X, Q, rng = cache_cluster
+    cache = ShardProbeCache(max_bytes=8 << 20)
+    c.coordinator.probe_cache = None
+    off = c.coordinator.probe("emb", Q[0], 10, strategy="diskann")
+    try:
+        c.coordinator.probe_cache = cache
+        cold = c.coordinator.probe("emb", Q[0], 10, strategy="diskann")
+        warm = c.coordinator.probe("emb", Q[0], 10, strategy="diskann")
+    finally:
+        c.coordinator.probe_cache = None
+    assert cold.cache is None and cold.shard_cache_hits == 0
+    assert warm.cache == "shard"
+    assert warm.shard_cache_hits == cold.probe_fragments > 0
+    assert warm.probe_fragments == 0
+
+    def exact(report):
+        return [
+            [(h.file_path, h.row_group, h.row_offset, h.distance) for h in hits]
+            for hits in report.hits
+        ]
+
+    assert exact(warm) == exact(cold) == exact(off)
+
+
 def test_invalidation_on_refresh_no_stale_hits(cache_cluster):
     c, t, X, Q, rng = cache_cluster
     cache = ShardProbeCache(max_bytes=8 << 20)

@@ -43,11 +43,11 @@ def _clean_doc():
             },
             "table2.filtered_hetero": {
                 "throughput_qps": 45.0,
-                "grouped_qps": 20.0,
-                "speedup_vs_grouped": 2.25,
+                "seq_qps": 20.0,
+                "speedup_vs_seq": 2.25,
                 "recall": 1.0,
                 "kernel_dispatches": 2,
-                "grouped_dispatches": 16,
+                "seq_dispatches": 16,
                 "distinct_filters": 8,
                 "parity_ok": True,
             },
@@ -55,11 +55,8 @@ def _clean_doc():
                 "throughput_qps": 40.0,
                 "recall": 1.0,
                 "kernel_dispatches": 2,
-                "split_dispatches": 4,
                 "probe_fragments": 2,
-                "speedup_vs_split": 1.4,
                 "distinct_filters": 8,
-                "parity_ok": True,
             },
             "table2.filtered_lowsel_bigshard": {
                 "throughput_qps": 30.0,
@@ -445,18 +442,18 @@ def test_cli_empty_or_rowless_input_is_an_error(tmp_path, capsys):
 
 def test_hetero_absolute_gates():
     """The mask-plane acceptance gates: worse-or-equal dispatch count than
-    the per-group path, speedup <= 1, parity breakage, and recall below the
-    floor each fail without any baseline."""
+    the one-query probes, speedup <= 1, parity breakage, and recall below
+    the floor each fail without any baseline."""
     cur = _clean_doc()
     h = cur["rows"]["table2.filtered_hetero"]
-    h["kernel_dispatches"] = 16  # == grouped: coalescing win lost
-    h["speedup_vs_grouped"] = 0.8
+    h["kernel_dispatches"] = 16  # == one-query probes: coalescing win lost
+    h["speedup_vs_seq"] = 0.8
     h["parity_ok"] = False
     h["recall"] = 0.90
     failures = check_bench.check(cur, None)
     assert any("no fewer kernel dispatches" in f for f in failures)
-    assert any("not above the per-predicate-group path" in f for f in failures)
-    assert any("diverge from the per-predicate-group" in f for f in failures)
+    assert any("not above the one-query probes" in f for f in failures)
+    assert any("diverge from the one-query probes" in f for f in failures)
     assert any("table2.filtered_hetero" in f and "recall vs oracle" in f for f in failures)
 
 
@@ -467,20 +464,13 @@ def test_hetero_clean_row_passes():
 
 def test_mixed_flavor_absolute_gates():
     """The unified-kernel acceptance gates: more than one dispatch per
-    shard, no dispatch win over the split path, a sub-1 fragment speedup,
-    broken parity, and recall below the floor each fail without any
-    baseline."""
+    shard and recall below the floor each fail without any baseline."""
     cur = _clean_doc()
     m = cur["rows"]["table2.filtered_mixed_flavor"]
-    m["kernel_dispatches"] = 4  # == split: two dispatches per shard again
-    m["speedup_vs_split"] = 0.9
-    m["parity_ok"] = False
+    m["kernel_dispatches"] = 4  # two dispatches per shard again
     m["recall"] = 0.90
     failures = check_bench.check(cur, None)
     assert any("exactly one kernel dispatch per shard" in f for f in failures)
-    assert any("no fewer" in f and "split-flavor" in f for f in failures)
-    assert any("not faster than the two-dispatch split-flavor" in f for f in failures)
-    assert any("diverge from the split-flavor" in f for f in failures)
     assert any(
         "table2.filtered_mixed_flavor" in f and "recall vs oracle" in f
         for f in failures
@@ -500,16 +490,16 @@ def test_hetero_gates_on_speedup_ratio_not_wall_clock():
     """filtered_hetero spans two scheduler waves, so its wall clock is as
     load-sensitive as the batched row's: a throughput drop alone must NOT
     fail (it is not baseline-throughput-gated), but the same-window
-    speedup_vs_grouped ratio falling to 1 must."""
+    speedup_vs_seq ratio falling to 1 must."""
     base = _clean_doc()
     cur = copy.deepcopy(base)
     cur["rows"]["table2.filtered_hetero"]["throughput_qps"] *= 0.4
-    cur["rows"]["table2.filtered_hetero"]["grouped_qps"] *= 0.4  # same window
+    cur["rows"]["table2.filtered_hetero"]["seq_qps"] *= 0.4  # same window
     assert check_bench.check(cur, base) == []
-    cur["rows"]["table2.filtered_hetero"]["speedup_vs_grouped"] = 0.97
+    cur["rows"]["table2.filtered_hetero"]["speedup_vs_seq"] = 0.97
     failures = check_bench.check(cur, base)
     assert any(
-        "table2.filtered_hetero" in f and "not above the per-predicate-group" in f
+        "table2.filtered_hetero" in f and "not above the one-query probes" in f
         for f in failures
     )
 

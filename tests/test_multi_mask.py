@@ -5,8 +5,8 @@ kernel-backed plans (prefilter / mask / unfiltered-in-a-mixed-fragment)
 issues exactly ONE masked-kernel dispatch per shard regardless of how many
 distinct predicates the batch carries — counted via
 ``Executor.masked_kernel_dispatches`` / ``ProbeReport.kernel_dispatches``
-— with per-query results identical to the legacy per-predicate-group loop
-(``Executor.force_group_loop=True`` re-enables it for comparison).
+— with per-query results identical to one-query ``probe()`` calls, which
+pay one kernel call per (query, shard).
 """
 
 import numpy as np
@@ -33,11 +33,6 @@ def _locs_d(hits):
 def _reset_dispatch_counters(c):
     for ex in c.executors:
         ex.masked_kernel_dispatches = 0
-
-
-def _set_group_loop(c, flag: bool):
-    for ex in c.executors:
-        ex.force_group_loop = flag
 
 
 def _queries(X, n, seed):
@@ -101,14 +96,13 @@ HETERO_FILTERS = [f"price < {5 + 9 * i}" for i in range(8)]  # est 0.05 .. 0.68
 
 def test_hetero_batch_is_one_dispatch_per_shard(plane_cluster):
     """8 distinct predicates in one batch: the mask-plane path answers each
-    coalesced fragment with exactly ONE kernel call, where the per-group
-    loop pays one call per distinct predicate — and the hits (including
-    distances) are identical between the two paths and exact vs the
-    brute-force oracle."""
+    coalesced fragment with exactly ONE kernel call, where one-query probes
+    pay one call per query per shard — and the hits (including distances)
+    are identical between the two and exact vs the brute-force oracle."""
     c, t, X, price, rep = plane_cluster
     Q = _queries(X, 8, seed=3)
     assert len(set(HETERO_FILTERS)) == 8
-    # warm: masks computed and cached on first touch (both paths share them)
+    # warm: masks computed and cached on first touch (every call shares them)
     c.coordinator.probe_batch("emb", Q, 10, strategy="diskann", filter=HETERO_FILTERS)
 
     _reset_dispatch_counters(c)
@@ -119,19 +113,16 @@ def test_hetero_batch_is_one_dispatch_per_shard(plane_cluster):
     assert br.kernel_dispatches == br.probe_fragments  # ONE call per shard
     assert sum(ex.masked_kernel_dispatches for ex in c.executors) == br.kernel_dispatches
 
-    _set_group_loop(c, True)
-    try:
-        _reset_dispatch_counters(c)
-        bg = c.coordinator.probe_batch(
-            "emb", Q, 10, strategy="diskann", filter=HETERO_FILTERS
-        )
-    finally:
-        _set_group_loop(c, False)
-    # legacy path: one kernel call per distinct predicate per shard
-    assert bg.kernel_dispatches == len(HETERO_FILTERS) * bg.probe_fragments
-    assert bg.kernel_dispatches > br.kernel_dispatches
-    for a, b in zip(br.hits, bg.hits):
-        assert _locs_d(a) == _locs_d(b)  # byte-identical to the group loop
+    singles = [
+        c.coordinator.probe("emb", Q[i], 10, strategy="diskann", filter=f)
+        for i, f in enumerate(HETERO_FILTERS)
+    ]
+    # one-query probes: one kernel call per distinct predicate per shard
+    seq_dispatches = sum(s.kernel_dispatches for s in singles)
+    assert seq_dispatches == len(HETERO_FILTERS) * br.probe_fragments
+    assert seq_dispatches > br.kernel_dispatches
+    for a, s in zip(br.hits, singles):
+        assert _locs_d(a) == _locs_d(s.hits[0])  # byte-identical per query
     # every plan is an exact kernel scan, so hits match the oracle exactly
     oracle = c.coordinator.probe_batch(
         "emb", Q, 10, strategy="scan", filter=HETERO_FILTERS
@@ -142,8 +133,8 @@ def test_hetero_batch_is_one_dispatch_per_shard(plane_cluster):
 
 def test_hetero_pq_batch_is_one_dispatch_per_shard(pq_plane_cluster):
     """On a PQ index, mid-selectivity mask plans all take the ADC flavor:
-    still one multi-mask kernel call per shard, and byte-identical to the
-    per-group path (per-query pool truncation keeps the rerank pools the
+    still one multi-mask kernel call per shard, and byte-identical to
+    one-query probes (per-query pool truncation keeps the rerank pools the
     same)."""
     c, t, X, price, rep = pq_plane_cluster
     Q = _queries(X, 8, seed=5)
@@ -155,14 +146,23 @@ def test_hetero_pq_batch_is_one_dispatch_per_shard(pq_plane_cluster):
     assert br.kernel_dispatches == br.probe_fragments
     assert "mask" in br.filter_plan
 
-    _set_group_loop(c, True)
-    try:
-        bg = c.coordinator.probe_batch("emb", Q, 10, strategy="diskann", filter=filters)
-    finally:
-        _set_group_loop(c, False)
-    assert bg.kernel_dispatches == len(set(filters)) * bg.probe_fragments
-    for a, b in zip(br.hits, bg.hits):
-        assert _locs_d(a) == _locs_d(b)
+    singles = [
+        c.coordinator.probe("emb", Q[i], 10, strategy="diskann", filter=f)
+        for i, f in enumerate(filters)
+    ]
+    assert sum(s.kernel_dispatches for s in singles) == (
+        len(set(filters)) * br.probe_fragments
+    )
+    for a, s in zip(br.hits, singles):
+        assert _locs_d(a) == _locs_d(s.hits[0])
+    oracle = c.coordinator.probe_batch(
+        "emb", Q, 10, strategy="scan", filter=filters
+    )
+    recall = np.mean([
+        len(set(_locs(a)) & set(_locs(b))) / max(len(_locs(a)), 1)
+        for a, b in zip(oracle.hits, br.hits)
+    ])
+    assert recall >= 0.95
 
 
 def test_pq_traversal_reranks_are_counted(pq_plane_cluster):
@@ -187,8 +187,8 @@ def test_pq_traversal_reranks_are_counted(pq_plane_cluster):
 
 def test_mixed_kernel_and_postfilter_batch_matches_sequential(plane_cluster):
     """A batch mixing unfiltered, mask-planned, and postfilter-planned
-    queries: kernel rows ride the plane, the beam group loop survives only
-    for the postfilter queries — and every query returns exactly what its
+    queries: kernel rows ride the plane, postfilter rows share the
+    over-fetched beam pass — and every query returns exactly what its
     sequential probe returns."""
     c, t, X, price, rep = plane_cluster
     Q = _queries(X, 5, seed=7)

@@ -5,8 +5,7 @@
   of selectivity thresholds);
 - a coalesced fragment mixing exact and PQ flavors with heterogeneous
   predicates completes in exactly ONE kernel dispatch per shard, with hits
-  bit-identical to the ``force_group_loop`` path AND the two-dispatch
-  ``force_split_flavors`` path;
+  bit-identical to one-query ``probe()`` calls;
 - an unfiltered query riding a MIXED fragment gets a shared Beam op (or a
   size-capped ExactScan below EXACT_SCAN_MAX_ROWS) — never an uncapped
   O(N·D) all-ones row.
@@ -313,11 +312,6 @@ MIXED_FILTERS = [
 ]
 
 
-def _set_flag(c, name, flag):
-    for ex in c.executors:
-        setattr(ex, name, flag)
-
-
 def _reset_dispatches(c):
     for ex in c.executors:
         ex.masked_kernel_dispatches = 0
@@ -327,8 +321,7 @@ def test_mixed_flavor_fragment_is_one_dispatch_per_shard(mixed_cluster):
     """THE tentpole acceptance: exact-flavor and PQ-flavor queries with
     heterogeneous predicates in one coalesced fragment cost exactly ONE
     kernel dispatch per shard (the unified kernel), with hits bit-identical
-    to the force_group_loop path and to the two-dispatch split-flavor
-    path."""
+    to one-query probes, which pay one dispatch per query per shard."""
     c, t, X, price, rep = mixed_cluster
     rng = np.random.default_rng(4)
     Q = X[rng.choice(len(X), 8)] + 0.05 * rng.normal(size=(8, DIM)).astype(np.float32)
@@ -351,31 +344,16 @@ def test_mixed_flavor_fragment_is_one_dispatch_per_shard(mixed_cluster):
     assert br.kernel_dispatches == br.probe_fragments  # ONE dispatch per shard
     assert sum(ex.masked_kernel_dispatches for ex in c.executors) == br.kernel_dispatches
 
-    # two-dispatch split-flavor path: same hits, one dispatch per flavor
-    _set_flag(c, "force_split_flavors", True)
-    try:
-        _reset_dispatches(c)
-        bs = c.coordinator.probe_batch(
-            "emb", Q, 10, strategy="diskann", filter=MIXED_FILTERS
-        )
-    finally:
-        _set_flag(c, "force_split_flavors", False)
-    assert bs.kernel_dispatches == 2 * bs.probe_fragments
-    for a, b in zip(br.hits, bs.hits):
-        assert _locs_d(a) == _locs_d(b)
-
-    # legacy per-predicate-group loop: one dispatch per distinct predicate
-    _set_flag(c, "force_group_loop", True)
-    try:
-        _reset_dispatches(c)
-        bg = c.coordinator.probe_batch(
-            "emb", Q, 10, strategy="diskann", filter=MIXED_FILTERS
-        )
-    finally:
-        _set_flag(c, "force_group_loop", False)
-    assert bg.kernel_dispatches == len(MIXED_FILTERS) * bg.probe_fragments
-    for a, b in zip(br.hits, bg.hits):
-        assert _locs_d(a) == _locs_d(b)  # bit-identical, distances included
+    # one-query probes: one dispatch per distinct predicate per shard
+    singles = [
+        c.coordinator.probe("emb", Q[i], 10, strategy="diskann", filter=f)
+        for i, f in enumerate(MIXED_FILTERS)
+    ]
+    assert sum(s.kernel_dispatches for s in singles) == (
+        len(MIXED_FILTERS) * br.probe_fragments
+    )
+    for a, s in zip(br.hits, singles):
+        assert _locs_d(a) == _locs_d(s.hits[0])  # bit-identical, distances included
 
     # and exact parity vs the brute-force oracle (every plan is exact or
     # ADC + full-precision rerank over >= 4*k_eff pools at this scale)
@@ -469,8 +447,10 @@ def test_unfiltered_rows_in_mixed_batch_get_planned_ops(mixed_cluster):
 
 def test_postfilter_rows_share_pooled_beam_with_fused_fallback(mixed_cluster):
     """Heterogeneous postfilter-planned predicates no longer loop per
-    predicate group: rows group by planner pool (one beam pass here), and
-    results still match both the group loop and sequential probes."""
+    predicate group: rows group by planner pool (one beam pass here), the
+    rows the beam under-delivers share at most one fused fallback dispatch
+    per fragment, and every query's hits, distances included, match its
+    one-query probe."""
     c, t, X, price, rep = mixed_cluster
     rng = np.random.default_rng(8)
     Q = X[rng.choice(len(X), 4)] + 0.05 * rng.normal(size=(4, DIM)).astype(np.float32)
@@ -479,23 +459,16 @@ def test_postfilter_rows_share_pooled_beam_with_fused_fallback(mixed_cluster):
         "emb", Q, 5, strategy="diskann", filter=filters, L=256
     )
     assert "postfilter" in br.filter_plan
-    _set_flag(c, "force_group_loop", True)
-    try:
-        bg = c.coordinator.probe_batch(
-            "emb", Q, 5, strategy="diskann", filter=filters, L=256
-        )
-    finally:
-        _set_flag(c, "force_group_loop", False)
-    for a, b in zip(br.hits, bg.hits):
-        assert _locs_d(a) == _locs_d(b)
-    seq = [
+    assert br.kernel_dispatches <= br.probe_fragments
+    singles = [
         c.coordinator.probe(
             "emb", Q[i], 5, strategy="diskann", filter=filters[i], L=256
-        ).hits[0]
+        )
         for i in range(4)
     ]
-    for a, b in zip(seq, br.hits):
-        assert _locs(a) == _locs(b)
+    assert br.kernel_dispatches <= sum(s.kernel_dispatches for s in singles)
+    for s, b in zip(singles, br.hits):
+        assert _locs_d(s.hits[0]) == _locs_d(b)
 
 
 def test_histogram_feeds_range_selectivity(mixed_cluster):

@@ -72,6 +72,23 @@ def test_property_batch_equals_sequential(built_cluster, b, k, strategy, seed):
     _assert_same_hits(seq, br.hits)
 
 
+def test_multi_query_centroid_probe_answers_each_query_alone(built_cluster):
+    """A several-query ``probe(strategy="centroid")`` answers each query from
+    its own ``n_probe`` files, not from the union of every query's files:
+    row for row it equals one-query probes, though the call reads the
+    union once."""
+    c, t, X, centers, rep = built_cluster
+    rng = np.random.default_rng(11)
+    Q = X[rng.choice(len(X), 8)] + 0.05 * rng.normal(size=(8, 32)).astype(np.float32)
+    multi = c.coordinator.probe("emb", Q, 10, strategy="centroid", n_probe=2)
+    singles = [
+        c.coordinator.probe("emb", q, 10, strategy="centroid", n_probe=2) for q in Q
+    ]
+    assert all(s.files_scanned == 2 for s in singles)
+    assert multi.files_scanned > 2  # the union is read, each query owns its own
+    _assert_same_hits([s.hits[0] for s in singles], multi.hits)
+
+
 def test_batch_probe_coalesces_fragments(built_cluster):
     """B queries × S shards of per-(query, shard) fragments must reach the
     executors as ≤ S coalesced fragments."""
