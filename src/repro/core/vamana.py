@@ -123,6 +123,20 @@ def _pair_dist(q: jnp.ndarray, v: jnp.ndarray, metric: str) -> jnp.ndarray:
     return jnp.sum(diff * diff, axis=-1)
 
 
+def _adc_dist(luts: jnp.ndarray, codes: jnp.ndarray) -> jnp.ndarray:
+    """PQ asymmetric distances: luts (B, m, K) f32, codes (B, R, m) int ->
+    (B, R) ``sum_j luts[b, j, codes[b, r, j]]``.
+
+    The TPU has no efficient per-lane gather (``take_along_axis`` over the
+    LUTs reads a whole tile per element), so each code selects its LUT entry
+    by a compare with ``iota(K)`` and one reduction over (m, K) sums them:
+    XLA fuses it without materialising the (B, R, m, K) one-hot.  The
+    selected f32 entries are exact; only the order of the sum over m differs
+    from the gather's."""
+    hit = codes[..., None] == jnp.arange(luts.shape[-1], dtype=codes.dtype)
+    return jnp.sum(jnp.where(hit, luts[:, None], 0.0), axis=(-1, -2))
+
+
 def _dedupe_sorted_by_id(ids, dists, expanded):
     """Mark duplicate ids invalid.  Inputs already sorted by id asc with
     expanded entries first within a run (so the surviving copy keeps its
@@ -159,10 +173,7 @@ def _beam_search(
     def dist_to(ids: jnp.ndarray) -> jnp.ndarray:  # ids (B, K) -> (B, K)
         safe = jnp.clip(ids, 0, cap - 1)
         if use_pq:
-            codes = vectors[safe]  # (B, K, m) int32
-            # luts: (B, m, Kcode); gather -> (B, m, K)
-            g = jnp.take_along_axis(queries, codes.transpose(0, 2, 1), axis=2)
-            d = jnp.sum(g, axis=1)
+            d = _adc_dist(queries, vectors[safe])  # code rows (B, K, m)
         else:
             v = vectors[safe]  # (B, K, D)
             d = _pair_dist(queries[:, None, :], v, metric)
@@ -274,9 +285,7 @@ def _masked_beam_search(
     def dist_to(ids: jnp.ndarray) -> jnp.ndarray:  # ids (B, K) -> (B, K)
         safe = jnp.clip(ids, 0, cap - 1)
         if use_pq:
-            codes = vectors[safe]  # (B, K, m) int32
-            g = jnp.take_along_axis(queries, codes.transpose(0, 2, 1), axis=2)
-            d = jnp.sum(g, axis=1)
+            d = _adc_dist(queries, vectors[safe])  # code rows (B, K, m)
         else:
             v = vectors[safe]  # (B, K, D)
             d = _pair_dist(queries[:, None, :], v, metric)

@@ -210,6 +210,31 @@ def test_beam_search_compiles_at_fewer_slots(one_chip, slots, use_pq, dim):
     assert compiled.memory_analysis() is not None
 
 
+@WIDTHS
+@pytest.mark.parametrize("slots", [16, 64])
+def test_pq_beam_search_has_no_lut_gather(one_chip, slots, dim):
+    """A step's PQ distances select each LUT entry by a one-hot compare: no
+    gather reads the (slots, m, 256) f32 LUTs (the TPU's per-lane gather
+    reads a whole tile per element), and the one-hot is fused, never
+    materialised as a (slots, R, m, 256) f32 buffer."""
+    pq_m = dim // PQ_DSUB
+    lowered = _beam_search.lower(
+        _sds((N, pq_m), jnp.int32, one_chip),
+        _sds((N, R), jnp.int32, one_chip),
+        _sds((), jnp.int32, one_chip),
+        _sds((), jnp.int32, one_chip),
+        _sds((slots, pq_m, PQ_K), jnp.float32, one_chip),
+        L, MAX_ITERS, "l2", True,
+    )
+    lut = f"tensor<{slots}x{pq_m}x{PQ_K}xf32>"
+    lut_gathers = [
+        line for line in lowered.as_text().splitlines() if "gather" in line and lut in line
+    ]
+    assert not lut_gathers
+    temp = lowered.compile().memory_analysis().temp_size_in_bytes
+    assert temp < slots * R * pq_m * PQ_K * 4
+
+
 def test_masked_beam_search_compiles(one_chip):
     compiled = _masked_beam_search.lower(
         _sds((N, D), jnp.float32, one_chip),

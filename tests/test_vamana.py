@@ -7,14 +7,17 @@ from hypothesis import given, settings, strategies as st
 import jax.numpy as jnp
 
 from repro.core.blobs import ShardLocationMap, decode_shard_blob, encode_shard_blob
-from repro.core.pq import encode, train_pq
+from repro.core.pq import build_luts, encode, train_pq
 from repro.core.vamana import (
     VamanaParams,
+    _adc_dist,
+    _beam_search,
     _robust_prune,
     brute_force_topk,
     build_vamana,
     recall_at_k,
 )
+from repro.kernels import ref
 from conftest import clustered_vectors
 
 
@@ -75,6 +78,50 @@ def test_pq_search_with_rerank(built):
     _, truth = brute_force_topk(X, Q, 10)
     _, got = g.search_pq(Q, 10)
     assert recall_at_k(got, truth) >= 0.75
+
+
+@pytest.mark.parametrize("m", [48, 96])
+def test_adc_dist_matches_reference(m):
+    """The traversal's one-hot ADC equals the gather reference row for row,
+    codes 0 and 255 included: the same f32 entries, summed in another order."""
+    rng = np.random.default_rng(m)
+    B, R, K = 5, 64, 256
+    luts = rng.random((B, m, K), dtype=np.float32) * 4
+    codes = rng.integers(0, K, (B, R, m)).astype(np.int32)
+    codes[:, 0], codes[:, 1] = 0, K - 1
+    codes[:, 2, ::2] = K - 1
+    got = np.asarray(_adc_dist(jnp.asarray(luts), jnp.asarray(codes)))
+    assert got.shape == (B, R)
+    for b in range(B):
+        want = np.asarray(ref.pq_adc_scores(jnp.asarray(luts[b : b + 1]), jnp.asarray(codes[b])))[0]
+        np.testing.assert_allclose(got[b], want, rtol=1e-6, atol=0)
+
+
+@pytest.mark.parametrize("dim", [768, 1536], ids=["D768", "D1536"])
+def test_pq_traversal_distances_are_reference_adc(dim):
+    """Every finite pool and visited distance of ``_beam_search(use_pq=True)``
+    at the deployments' widths (16-d PQ subspaces) is the reference ADC
+    distance of its id."""
+    rng = np.random.default_rng(dim)
+    X, _ = clustered_vectors(rng, n_clusters=8, per_cluster=64, dim=dim)
+    g = build_vamana(X, VamanaParams(R=16, L=32), seed=0, passes=1, batch=128)
+    pq = train_pq(X, m=dim // 16, nbits=8, iters=4)
+    codes = encode(pq, X)
+    Q = X[rng.choice(len(X), 16)] + 0.1 * rng.normal(size=(16, dim)).astype(np.float32)
+    luts = build_luts(pq, Q)
+    cap_codes = np.zeros((g.vectors.shape[0], pq.m), np.int32)
+    cap_codes[: g.n] = codes
+    ids, dists, vis_ids, vis_dists = _beam_search(
+        jnp.asarray(cap_codes), jnp.asarray(g.adjacency), jnp.int32(g.n),
+        jnp.int32(g.medoid), luts, 32, int(1.3 * 32) + 8, "l2", True,
+    )
+    all_ids = np.concatenate([np.asarray(ids), np.asarray(vis_ids)], axis=1)
+    all_d = np.concatenate([np.asarray(dists), np.asarray(vis_dists)], axis=1)
+    want = np.asarray(ref.pq_adc_scores(luts, jnp.asarray(codes)))  # (Q, n)
+    fin = np.isfinite(all_d)
+    assert fin[:, :10].all()
+    rows = np.nonzero(fin)[0]
+    np.testing.assert_allclose(all_d[fin], want[rows, all_ids[fin]], rtol=1e-6, atol=0)
 
 
 def test_insert_then_search(built):
