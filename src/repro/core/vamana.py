@@ -250,7 +250,7 @@ def _masked_beam_search(
     n_valid: jnp.ndarray,  # () int32
     entry: jnp.ndarray,  # () int32
     queries: jnp.ndarray,  # (B, D) f32     (or LUTs (B, m, K) f32 if use_pq)
-    mask_unique: jnp.ndarray,  # (m, cap) bool — True = admissible
+    mask_unique: jnp.ndarray,  # (B, cap) bool — True = admissible; unused rows all-false
     mask_idx: jnp.ndarray,  # (B,) int32 — query row -> mask row
     L: int,
     k_res: int,
@@ -272,7 +272,9 @@ def _masked_beam_search(
     mask ships dedup'd: ``mask_unique`` holds the
     distinct admissibility rows, ``mask_idx`` maps each query to its row
     (the PR 5 dedup-then-broadcast shape — the (B, cap) plane is expanded by
-    gather on device, never materialized on host).
+    gather on device, never materialized on host).  Callers pad the distinct
+    rows to the call's B slots, so the number of distinct masks never names
+    a new program.
 
     Returns (res_ids (B, k_res), res_dists (B, k_res), vis_ids
     (B, max_iters)).  Result rows ascend by distance; slots the traversal
@@ -625,6 +627,9 @@ class VamanaGraph:
         graph ids (True = admissible; the caller folds tombstones in —
         admissibility means *predicate AND NOT tombstoned*); ``mask_idx``
         maps each query to its mask row (default: all queries share row 0).
+        A traversal call uploads only its own chunk's distinct rows, padded
+        with all-false rows to the call's ``query_slots``: one program per
+        slot bucket, however many distinct predicates a call carries.
         With ``use_pq`` the traversal runs on ADC distances and the admitted
         pool ∪ admissible visited nodes get a full-precision host rerank.
 
@@ -648,15 +653,12 @@ class VamanaGraph:
         unique_masks = np.asarray(unique_masks, dtype=bool)
         if unique_masks.ndim == 1:
             unique_masks = unique_masks[None, :]
-        mask_pad = np.zeros((unique_masks.shape[0], cap), dtype=bool)
         width = min(unique_masks.shape[1], cap)
-        mask_pad[:, :width] = unique_masks[:, :width]
         idx_np = (
             np.zeros(Q, np.int32)
             if mask_idx is None
             else np.asarray(mask_idx, np.int32)
         )
-        masks_j = jnp.asarray(mask_pad)
         out_d = np.full((Q, k), np.inf, np.float32)
         out_i = np.full((Q, k), -1, np.int64)
         max_iters = int(1.3 * L) + 8
@@ -672,7 +674,14 @@ class VamanaGraph:
             q = queries[s : s + batch]
             slots = query_slots(q.shape[0])
             qb = _pad_rows(q, slots)
-            ib = _pad_rows(idx_np[s : s + batch], slots)
+            # the chunk's own mask rows, padded with all-false rows to its
+            # slots: one program per slot bucket, whatever the number of
+            # distinct masks; padded query slots point at row 0
+            rows, ib = np.unique(idx_np[s : s + batch], return_inverse=True)
+            ib = _pad_rows(ib.astype(np.int32), slots)
+            plane = np.zeros((slots, cap), dtype=bool)
+            plane[: len(rows), :width] = unique_masks[rows, :width]
+            masks_j = jnp.asarray(plane)
             if use_pq:
                 luts = build_luts(self.pq, qb)
                 res_i, _res_d, vis_i = _masked_beam_search(
@@ -700,7 +709,7 @@ class VamanaGraph:
                 sort_idx = np.argsort(cand, axis=1, kind="stable")
                 s_ids = np.take_along_axis(cand, sort_idx, axis=1)
                 safe = np.clip(s_ids, 0, cap - 1)
-                adm = mask_pad[ib[:, None], safe] & (s_ids < self.n)
+                adm = plane[ib[:, None], safe] & (s_ids < self.n)
                 dup = np.concatenate(
                     [
                         np.zeros((cand.shape[0], 1), bool),

@@ -299,10 +299,14 @@ class Executor:
         """Tally MaskedBeam accounting for the current task: how many rows
         the predicate-aware traversal answered, and how many of those
         under-delivered and were re-answered by the fused exact-masked
-        fallback (thread-local, reset per task like the dispatch count)."""
+        fallback (thread-local, reset per task like the dispatch count;
+        with tracing on, also the counters ``masked_beam.rows`` and
+        ``masked_beam.fallbacks``)."""
         t = self._dispatch_tls
         t.mbeam_rows = getattr(t, "mbeam_rows", 0) + rows
         t.mbeam_fallbacks = getattr(t, "mbeam_fallbacks", 0) + fallbacks
+        count("masked_beam.rows", rows)
+        count("masked_beam.fallbacks", fallbacks)
 
     def _task_mbeam(self) -> Tuple[int, int]:
         t = self._dispatch_tls
@@ -368,7 +372,9 @@ class Executor:
         rgrp = np.asarray(locmap.row_group[:n], np.int64)
         roff = np.asarray(locmap.row_offset[:n], np.int64)
         readers: Dict[str, VParquetReader] = {}
-        for fi, rg in {(int(a), int(b)) for a, b in zip(fidx, rgrp)}:
+        groups = int(rgrp.max(initial=0)) + 1
+        for pair in np.unique(fidx * groups + rgrp):  # each (file, row group) once
+            fi, rg = divmod(int(pair), groups)
             fpath = locmap.file_paths[fi]
             if fpath not in readers:
                 readers[fpath] = VParquetReader.from_store(self.store, fpath)
@@ -910,7 +916,8 @@ class Executor:
                     w = op.width if isinstance(op, planner.Beam) else 0
                     beam_rows.setdefault(int(w), []).append(bi)
                 continue
-            live = self._predicate_mask(locmap, n, pred, shard_key) & tomb_live
+            with span("executor.predicate_mask"):
+                live = self._predicate_mask(locmap, n, pred, shard_key) & tomb_live
             final = self._resolve_op(task, op, live, graph.pq is not None)
             if isinstance(final, planner.Skip):
                 result.candidates[int(qidx[bi])] = []
@@ -1073,14 +1080,18 @@ class Executor:
             k = max(ks_by_row[bi] for bi in rows)
             if use_pq:
                 self._count_graph_reranks(queries)
-            dists, ids = graph.search_masked(
-                queries,
-                max(1, min(int(width), graph.num_live)),
-                np.stack(unique),
-                idx,
-                L=max(task.L, min(int(k), graph.num_live)),
-                use_pq=use_pq,
-            )
+            slots = stream_slots(len(rows))
+            count("traversal.padded_slots", slots - len(rows))
+            with span("traversal.search_masked", queries=len(rows), slots=slots,
+                      masks=len(unique)):
+                dists, ids = graph.search_masked(
+                    queries,
+                    max(1, min(int(width), graph.num_live)),
+                    np.stack(unique),
+                    idx,
+                    L=max(task.L, min(int(k), graph.num_live)),
+                    use_pq=use_pq,
+                )
             total += len(rows)
             for j, bi in enumerate(rows):
                 kj = ks_by_row[bi]

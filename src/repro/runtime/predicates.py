@@ -304,6 +304,21 @@ class Range(_Leaf):
             out &= (arr <= self.hi) if self.hi_inclusive else (arr < self.hi)
         return out
 
+    def intersect(self, other: "Range") -> "Range":
+        """The range both ``self`` and ``other`` (on the same column) pass:
+        the tighter bound on each side, the exclusive one on a tie."""
+        lo, lo_inc = self.lo, self.lo_inclusive
+        if other.lo is not None and (
+            lo is None or other.lo > lo or (other.lo == lo and not other.lo_inclusive)
+        ):
+            lo, lo_inc = other.lo, other.lo_inclusive
+        hi, hi_inc = self.hi, self.hi_inclusive
+        if other.hi is not None and (
+            hi is None or other.hi < hi or (other.hi == hi and not other.hi_inclusive)
+        ):
+            hi, hi_inc = other.hi, other.hi_inclusive
+        return Range(self.column, lo=lo, hi=hi, lo_inclusive=lo_inc, hi_inclusive=hi_inc)
+
     def zone_may_match(self, zones):
         z = zones.get(self.column)
         if z is None:
@@ -373,8 +388,19 @@ class And(Predicate):
         return all(c.zone_may_match(zones) for c in self.children)
 
     def estimate_fraction(self, zones):
-        f = 1.0
+        """Product of the children's fractions, taken as independent, once
+        the range bounds on one column are intersected into one range: two
+        bounds on one column are not independent (``price >= 4500 AND
+        price < 5500`` passes 10% of a uniform [0, 10000), not 0.55 * 0.55)."""
+        ranges: Dict[str, Range] = {}
+        others: List[Predicate] = []
         for c in self.children:
+            if isinstance(c, Range):
+                ranges[c.column] = ranges[c.column].intersect(c) if c.column in ranges else c
+            else:
+                others.append(c)
+        f = 1.0
+        for c in (*ranges.values(), *others):
             f *= c.estimate_fraction(zones)
         return f
 

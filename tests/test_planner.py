@@ -526,6 +526,31 @@ def test_histogram_estimate_respects_strict_int_bounds():
     assert est_gt == pytest.approx(0.05, abs=0.02)
 
 
+@pytest.mark.parametrize("hist", [True, False], ids=["histogram", "span"])
+@pytest.mark.parametrize("lo", [0, 4500, 9000])
+def test_two_bounds_on_one_column_estimate_their_band(lo, hist):
+    """``price >= lo AND price < lo + 1000`` on a uniform [0, 10000) column
+    passes 10% wherever the band lies: the two bounds intersect into one
+    range, and are not multiplied as independent predicates (0.55 * 0.55 at
+    lo=4500), whose over-estimate would narrow the MaskedBeam width."""
+    from repro.runtime.predicates import (
+        ColumnHistogram, Range, ZoneStats, parse_predicate,
+    )
+
+    col = np.random.default_rng(1).integers(0, 10000, 8192)
+    z = {"price": ZoneStats(count=len(col), min=int(col.min()), max=int(col.max()),
+                            hist=ColumnHistogram.build(col) if hist else None)}
+    pred = parse_predicate(f"price >= {lo} AND price < {lo + 1000}")
+    true_frac = float(((col >= lo) & (col < lo + 1000)).mean())
+    assert pred.estimate_fraction(z) == pytest.approx(true_frac, abs=0.01)
+    # disjoint bounds pass nothing; a third bound on the column folds in too
+    assert parse_predicate("price >= 6000 AND price < 5000").estimate_fraction(z) == 0.0
+    both = parse_predicate(f"price >= {lo} AND price < {lo + 1000} AND price <= 99999")
+    assert both.estimate_fraction(z) == pytest.approx(true_frac, abs=0.01)
+    a = Range("price", lo=10, lo_inclusive=False).intersect(Range("price", lo=10, hi=20))
+    assert (a.lo, a.lo_inclusive, a.hi, a.hi_inclusive) == (10, False, 20, True)
+
+
 def test_histogram_estimate_beats_span_on_skew():
     from repro.runtime.predicates import ColumnHistogram, Range, ZoneStats
 

@@ -108,7 +108,9 @@ def _old_search(g, Q, use_pq):
     return out_d, out_i
 
 
-def _old_search_masked(g, Q, masks, use_pq):
+def _old_search_masked(g, Q, masks, use_pq, depth=L):
+    """64-slot padding, and the whole (m, cap) plane of distinct masks in
+    every call."""
     idx = np.arange(len(Q), dtype=np.int32) % len(masks)
     out_d, out_i = np.empty((len(Q), K), np.float32), np.empty((len(Q), K), np.int64)
     points = jnp.asarray(g.pq_codes.astype(np.int32) if use_pq else g.vectors)
@@ -119,7 +121,7 @@ def _old_search_masked(g, Q, masks, use_pq):
         x = build_luts(g.pq, qb) if use_pq else jnp.asarray(qb)
         res_i, res_d, vis = _masked_beam_search(
             points, jnp.asarray(g.adjacency), jnp.int32(g.n), jnp.int32(g.medoid), x,
-            jnp.asarray(mask_pad), jnp.asarray(ib), L, K, int(1.3 * L) + 8, "l2", use_pq,
+            jnp.asarray(mask_pad), jnp.asarray(ib), depth, K, int(1.3 * depth) + 8, "l2", use_pq,
         )
         if use_pq:
             cand = np.concatenate([np.asarray(res_i), np.asarray(vis)], 1)
@@ -175,6 +177,27 @@ def test_no_query_count_compiles_after_a_shapes_first_traversal(graph, method):
         metrics.drain()
     assert reg.counter_value("compiles", "first") > 0
     assert reg.counter_value("compiles", "later") == 0
+
+
+@pytest.mark.parametrize("use_pq", [False, True], ids=["exact", "pq"])
+def test_distinct_masks_share_one_program_per_slot_bucket(graph, use_pq):
+    """``search_masked`` pads a call's distinct mask rows to its slots: calls
+    of one slot bucket carrying 1, 3, 7 and 16 distinct masks run one
+    program, and answer as the unpadded (m, cap) plane does."""
+    g, Q, _ = graph
+    depth = L + 6  # a shape no other test of the module traverses
+    rng = np.random.default_rng(11)
+    planes = {m: rng.random((m, g.n)) < rng.uniform(0.2, 0.9, (m, 1)) for m in (1, 3, 7, 16)}
+    before = _masked_beam_search._cache_size()
+    got = {
+        m: g.search_masked(Q[:16], K, masks, np.arange(16) % m, L=depth, use_pq=use_pq)
+        for m, masks in planes.items()
+    }
+    assert _masked_beam_search._cache_size() == before + 1
+    for m, (d, i) in got.items():
+        d_old, i_old = _old_search_masked(g, Q[:16], planes[m], use_pq, depth=depth)
+        np.testing.assert_array_equal(i, i_old)
+        np.testing.assert_array_equal(d, d_old)
 
 
 @pytest.mark.parametrize(

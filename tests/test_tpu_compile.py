@@ -249,6 +249,26 @@ def test_masked_beam_search_compiles(one_chip):
     assert compiled.memory_analysis() is not None
 
 
+@pytest.mark.parametrize("slots", [16, 64])
+def test_masked_pq_beam_search_compiles_at_the_filtered_cells_shapes(one_chip, slots):
+    """The ``cohere-768d.range-10pct`` traversal: an 8,192-row shard's PQ
+    codes (m=48), one mask row a slot (``search_masked`` pads a call's
+    distinct rows to its slots), the planner's MaskedBeam width 160 as the
+    admit target, L=100."""
+    cap = 8192
+    compiled = _masked_beam_search.lower(
+        _sds((cap, PQ_M), jnp.int32, one_chip),
+        _sds((cap, R), jnp.int32, one_chip),
+        _sds((), jnp.int32, one_chip),
+        _sds((), jnp.int32, one_chip),
+        _sds((slots, PQ_M, PQ_K), jnp.float32, one_chip),
+        _sds((slots, cap), jnp.bool_, one_chip),
+        _sds((slots,), jnp.int32, one_chip),
+        L, 160, MAX_ITERS, "l2", True,
+    ).compile()
+    assert compiled.memory_analysis() is not None
+
+
 @WIDTHS
 def test_robust_prune_compiles(one_chip, dim):
     batch = 128

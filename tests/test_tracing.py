@@ -188,6 +188,56 @@ def test_traversal_spans_carry_queries_and_slots_and_count_the_padding(registry,
     assert registry.counter_value("traversal.padded_slots") == padded
 
 
+@pytest.fixture(scope="module")
+def filtered_cluster(tmp_path_factory):
+    """One 1,200-row shard with a uniform int ``price`` column."""
+    from repro.lakehouse.table import LakehouseTable
+    from repro.runtime.cluster import make_local_cluster
+    from repro.runtime.coordinator import IndexConfig
+
+    rng = np.random.default_rng(19)
+    c = make_local_cluster(str(tmp_path_factory.mktemp("filtered")), num_executors=1)
+    t = LakehouseTable(c.catalog, "emb")
+    t.create(dim=16)
+    X = rng.normal(size=(1200, 16)).astype(np.float32)
+    price = rng.integers(0, 100, len(X)).astype(np.int64)
+    t.append_vectors(X, num_files=2, rows_per_group=300, attributes={"price": price})
+    c.coordinator.create_index("emb", IndexConfig(
+        name="idx", num_shards=1, R=16, L=32, pq_m=4, build_passes=1))
+    return c, X
+
+
+def test_masked_traversal_spans_and_counters(registry, filtered_cluster, monkeypatch):
+    """Per-probe bands on a shard the planner treats as too large to scan:
+    each probe's mask is built under ``executor.predicate_mask``, the
+    MaskedBeam rows share one ``traversal.search_masked`` span carrying
+    ``queries``, ``slots`` and ``masks``, and the ``masked_beam.*`` counters
+    agree with the ``ProbeReport``."""
+    from repro.core.vamana import query_slots
+    from repro.runtime import planner
+
+    monkeypatch.setattr(planner, "EXACT_SCAN_MAX_ROWS", 512)
+    c, X = filtered_cluster
+    rng = np.random.default_rng(6)
+    n = 21
+    los = rng.choice(80, n, replace=False)
+    rep = c.coordinator.probe_batch(
+        "emb", X[rng.choice(len(X), n, replace=False)] + 0.01, 5, strategy="diskann",
+        filter=[f"price >= {lo} AND price < {lo + 20}" for lo in los])
+    spans = metrics.drain()
+    by_id = _by_id(spans)
+    masks = [s for s in spans if s["name"] == "executor.predicate_mask"]
+    assert len(masks) == n
+    assert {by_id[s["parent_id"]]["name"] for s in masks} == {"executor.task"}
+    (trav,) = [s for s in spans if s["name"] == "traversal.search_masked"]
+    assert by_id[trav["parent_id"]]["name"] == "executor.task"
+    assert trav["attrs"] == {"queries": n, "slots": query_slots(n), "masks": n}
+    assert rep.masked_beam_rows == n
+    assert registry.counter_value("masked_beam.rows") == rep.masked_beam_rows
+    assert registry.counter_value("masked_beam.fallbacks") == rep.masked_beam_fallbacks
+    assert registry.counter_value("traversal.padded_slots") == query_slots(n) - n
+
+
 def test_padded_slots_are_not_counted_with_tracing_off(built_cluster):
     reg = MetricsRegistry()
     metrics.set_tracing(reg)
